@@ -1281,7 +1281,7 @@ def run(csv: Csv, n_requests: int = 60) -> dict:
 if __name__ == "__main__":
     # CI smoke entry: the autoscale + heterogeneous-fleet sections alone,
     # tiny traces, loose checks (exercises the SCALER_POLICIES registry,
-    # both substrates, the Pallas-interpret pmf_conv signal path, the
+    # both substrates, the Pallas pmf_conv signal path, the
     # FleetSpec plumbing and the cost-aware heuristics without the model
     # benchmarks)
     import argparse

@@ -44,10 +44,10 @@ def tune_block_s(s: int, block_s: int = 512, floor: int = 128) -> int:
     return best
 
 
-def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, *,
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
             block_s: int, hd: int):
     sb = pl.program_id(2)
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(sb == 0)
     def _init():
@@ -84,7 +84,7 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, *,
 
 
 def decode_attention_pallas(q, k_cache, v_cache, lengths,
-                            block_s: int = 512, interpret: bool = True):
+                            block_s: int = 512, interpret: bool = False):
     """q (B, H, hd); k/v (B, S, Hkv, hd); lengths (B,) -> (B, H, hd)."""
     b, s, hkv, hd = k_cache.shape
     h = q.shape[1]
@@ -101,35 +101,42 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths,
     vt = jnp.swapaxes(v_cache, 1, 2)
 
     kernel = functools.partial(_kernel, block_s=block_s, hd=hd)
-    out, m, l = pl.pallas_call(
-        kernel,
+    # lengths ride as a scalar-prefetch operand (SMEM): a rank-1 VMEM block
+    # of one element is not a layout the TPU's (8, 128) tiling accepts
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, hkv, sp // block_s),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, k: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, hd), lambda i, j, k: (i, j, k, 0)),
-            pl.BlockSpec((1, 1, block_s, hd), lambda i, j, k: (i, j, k, 0)),
-            pl.BlockSpec((1,), lambda i, j, k: (i,)),
+            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, block_s, hd),
+                         lambda i, j, k, n: (i, j, k, 0)),
+            pl.BlockSpec((1, 1, block_s, hd),
+                         lambda i, j, k, n: (i, j, k, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, k: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, k: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, k: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, n: (i, j, 0, 0)),
         ],
+    )
+    out, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(qg.reshape(b, hkv, g, hd), kt, vt, lengths.astype(jnp.int32))
+    )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(b, h, hd).astype(q.dtype)
 
 
-def _paged_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
+def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, *, page_size: int, hd: int):
     del tables_ref  # consumed by the BlockSpec index maps (scalar prefetch)
     j = pl.program_id(2)
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -138,8 +145,8 @@ def _paged_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[0, 0]                     # (G, hd)
-    k = k_ref[0, :, 0]                  # (PS, hd)
-    v = v_ref[0, :, 0]
+    k = k_ref[0, 0]                     # (PS, hd)
+    v = v_ref[0, 0]
     scale = 1.0 / math.sqrt(hd)
 
     s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T) * scale
@@ -164,39 +171,43 @@ def _paged_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
-                                  interpret: bool = True):
+                                  interpret: bool = False):
     """Flash-decode over paged (non-contiguous) KV storage.
 
-    q (B, H, hd); k/v_pages (NP, PS, Hkv, hd); block_tables (B, MP) int32
+    q (B, H, hd); k/v_pages (NP, Hkv, PS, hd); block_tables (B, MP) int32
     page indices per sequence; lengths (B,) -> (B, H, hd).
 
     Same online-softmax carry as the contiguous kernel, but the kv block
     for grid step (b, g, j) is gathered through the block-table ref: the
     BlockSpec index map reads ``tables[b, j]`` via scalar prefetch
     (``pltpu.PrefetchScalarGridSpec``), so each sequence streams its own
-    scattered pages through VMEM.  Ragged ``lengths`` are handled by the
+    scattered pages through VMEM.  The kv-head axis sits before the page
+    slot so that each block is one (PS, hd) tile of one head — the last two
+    block dimensions then meet the TPU's (8, 128) tiling rule.  Ragged
+    ``lengths`` (the second scalar-prefetch operand) are handled by the
     positional mask — table entries past a sequence's last page may point
     anywhere (conventionally page 0) and contribute nothing.
     """
-    np_, ps, hkv, hd = k_pages.shape
+    np_, hkv, ps, hd = k_pages.shape
     b, h = q.shape[0], q.shape[1]
     g = h // hkv
     mp = block_tables.shape[1]
     qg = q.reshape(b, hkv, g, hd)
     kernel = functools.partial(_paged_kernel, page_size=ps, hd=hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, hkv, mp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, t: (i, j, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), lambda i, j, k, t: (t[i, k], 0, j, 0)),
-            pl.BlockSpec((1, ps, 1, hd), lambda i, j, k, t: (t[i, k], 0, j, 0)),
-            pl.BlockSpec((1,), lambda i, j, k, t: (i,)),
+            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, t, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda i, j, k, t, n: (t[i, k], j, 0, 0)),
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda i, j, k, t, n: (t[i, k], j, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, t: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, t: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, t: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, t, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, t, n: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, t, n: (i, j, 0, 0)),
         ],
     )
     out, m, l = pl.pallas_call(
@@ -208,6 +219,6 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), qg, k_pages, v_pages,
-      lengths.astype(jnp.int32))
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg,
+      k_pages, v_pages)
     return out.reshape(b, h, hd).astype(q.dtype)

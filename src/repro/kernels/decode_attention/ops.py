@@ -15,6 +15,7 @@ from functools import partial
 
 import jax
 
+from .. import interpret_default
 from .decode_attention import (decode_attention_pallas,
                                paged_decode_attention_pallas, tune_block_s)
 from .ref import decode_attention_ref, paged_decode_attention_ref
@@ -24,14 +25,9 @@ __all__ = ["decode_attention", "paged_decode_attention", "tune_block_s",
            "interpret_default"]
 
 
-def interpret_default() -> bool:
-    """True when no TPU/GPU is present (Pallas must run interpreted)."""
-    return jax.default_backend() not in ("tpu", "gpu")
-
-
 @partial(jax.jit, static_argnames=("block_s", "interpret", "use_kernel"))
-def _decode_attention_jit(q, k_cache, v_cache, lengths, block_s: int = 512,
-                          interpret: bool = True, use_kernel: bool = True):
+def _decode_attention_jit(q, k_cache, v_cache, lengths, *, block_s: int,
+                          interpret: bool, use_kernel: bool):
     if use_kernel:
         return decode_attention_pallas(q, k_cache, v_cache, lengths,
                                        block_s=block_s, interpret=interpret)
@@ -50,8 +46,7 @@ def decode_attention(q, k_cache, v_cache, lengths, block_s: int = 512,
 
 @partial(jax.jit, static_argnames=("interpret", "use_kernel"))
 def _paged_decode_attention_jit(q, k_pages, v_pages, block_tables, lengths,
-                                interpret: bool = True,
-                                use_kernel: bool = True):
+                                *, interpret: bool, use_kernel: bool):
     if use_kernel:
         return paged_decode_attention_pallas(q, k_pages, v_pages,
                                              block_tables, lengths,

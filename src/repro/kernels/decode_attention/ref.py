@@ -27,7 +27,7 @@ def decode_attention_ref(q, k_cache, v_cache, lengths):
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     """Oracle for the paged kernel: gather pages into a contiguous cache.
 
-    q (B, H, hd); k/v_pages (NP, PS, Hkv, hd); block_tables (B, MP);
+    q (B, H, hd); k/v_pages (NP, Hkv, PS, hd); block_tables (B, MP);
     lengths (B,) -> (B, H, hd).
 
     The arithmetic mirrors ``models.layers.decode_attention`` *exactly*
@@ -37,12 +37,16 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     replaces and batched greedy outputs match sequential ones token for
     token.
     """
-    np_, ps, hkv, hd = k_pages.shape
+    np_, hkv, ps, hd = k_pages.shape
     b, mp = block_tables.shape
     h = q.shape[1]
     g = h // hkv
-    kc = k_pages[block_tables].reshape(b, mp * ps, hkv, hd)
-    vc = v_pages[block_tables].reshape(b, mp * ps, hkv, hd)
+
+    def gather(pages):  # (B, MP, Hkv, PS, hd) -> (B, MP * PS, Hkv, hd)
+        return jnp.swapaxes(pages[block_tables], 2, 3).reshape(
+            b, mp * ps, hkv, hd)
+
+    kc, vc = gather(k_pages), gather(v_pages)
     qg = q.reshape(b, hkv, g, hd)
     scale = 1.0 / math.sqrt(hd)
     scores = jnp.einsum("bkgd,bskd->bkgs", qg, kc).astype(jnp.float32) * scale

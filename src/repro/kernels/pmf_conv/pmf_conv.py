@@ -8,9 +8,10 @@ dense batched computation — this kernel evaluates a whole mapping event's
 (batch x machine) chance-of-success matrix in one launch.
 
 Grid: (N / BN,) — one program per batch tile.
-Blocks (VMEM): pet (BN, Le), pct (BN, Lc), dl (BN, 1) -> out (BN, Lo),
+Blocks (VMEM): pet (BN, Le), pct (BN, Lo), dl (BN, 1) -> out (BN, Lo),
 success (BN, 1).  The inner loop runs Le vector FMAs on (BN, Lo) lanes —
-VPU-friendly; Lo is padded to a multiple of 128 (lane width) by ops.py.
+VPU-friendly; Lo is padded to a multiple of 128 (lane width) and the PCT
+zero-padded onto it before the launch.
 
 Semantics match ``ref.pmf_conv_ref`` (PEND_DROP, Eq. 5.4):
   out     = conv(pet, pct * [t < dl]) + passthrough(pct * [t >= dl])
@@ -19,60 +20,42 @@ Semantics match ``ref.pmf_conv_ref`` (PEND_DROP, Eq. 5.4):
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(pet_ref, pct_ref, dl_ref, out_ref, suc_ref, *, le: int, lc: int,
-            lo: int):
+def _kernel(pet_ref, pct_ref, dl_ref, out_ref, suc_ref):
     pet = pet_ref[...]                       # (BN, Le)
-    pct = pct_ref[...]                       # (BN, Lc)
+    pct = pct_ref[...]                       # (BN, Lo), zero past Lc
     dl = dl_ref[...]                         # (BN, 1) f32 (deadline index)
 
-    bn = pet.shape[0]
-    t_c = jax.lax.broadcasted_iota(jnp.float32, (bn, lc), 1)
-    ok = (t_c < dl).astype(pct.dtype)
-    pct_ok = pct * ok
-    pct_late = pct * (1.0 - ok)
-
-    # pad the truncated PCT to the output length once (VMEM scratch-free)
-    pad = jnp.zeros((bn, lo - lc), pct.dtype)
-    base = jnp.concatenate([pct_ok, pad], axis=1)      # (BN, Lo)
-    t_o = jax.lax.broadcasted_iota(jnp.float32, (bn, lo), 1)
+    bn, lo = pct.shape
+    le = pet.shape[1]
+    t_i = jax.lax.broadcasted_iota(jnp.int32, (bn, lo), 1)
+    t_o = t_i.astype(jnp.float32)            # Mosaic's iota is integer-only
+    ok = (t_o < dl).astype(pct.dtype)
+    base = pct * ok                          # truncated PCT on the out grid
+    t_e = jax.lax.broadcasted_iota(jnp.int32, (bn, le), 1)
 
     def body(k, acc):
-        # shift-right base by k: out += pet[:, k] * pct_ok[t - k]
-        shifted = _shift_right(base, k, lo)
-        return acc + pet[:, k][:, None] * shifted
+        # out += pet[:, k] * pct_ok[t - k]: column k of pet by a masked sum
+        # (exact: one nonzero term) and the shift by a lane rotate — Mosaic
+        # lowers neither a dynamic lane slice nor a gather
+        pet_k = jnp.sum(jnp.where(t_e == k, pet, 0.0), axis=1, keepdims=True)
+        shifted = jnp.where(t_i >= k, pltpu.roll(base, k, 1), 0.0)
+        return acc + pet_k * shifted
 
     acc = jax.lax.fori_loop(0, le, body,
                             jnp.zeros((bn, lo), jnp.float32))
     suc_ref[...] = jnp.sum(
         jnp.where(t_o <= dl, acc, 0.0), axis=1, keepdims=True)
-    late_pad = jnp.concatenate([pct_late, pad], axis=1)
-    out_ref[...] = acc + late_pad
-
-
-def _shift_right(x: jnp.ndarray, k, lo: int) -> jnp.ndarray:
-    """x shifted right by dynamic k along the lane axis, zero-filled."""
-    t = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    rolled = _roll(x, k)
-    return jnp.where(t >= k, rolled, 0.0)
-
-
-def _roll(x: jnp.ndarray, k) -> jnp.ndarray:
-    # dynamic circular roll along axis 1 (pltpu.roll exists on TPU; use the
-    # portable gather formulation so interpret mode works everywhere)
-    lo = x.shape[1]
-    idx = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) - k) % lo
-    return jnp.take_along_axis(x, idx, axis=1)
+    out_ref[...] = acc + pct * (1.0 - ok)
 
 
 def pmf_conv_pallas(pet: jnp.ndarray, pct: jnp.ndarray, dl: jnp.ndarray,
-                    block_n: int = 8, interpret: bool = True):
+                    block_n: int = 8, interpret: bool = False):
     """Batched PEND_DROP convolution.  pet (N, Le), pct (N, Lc), dl (N,).
 
     Returns (out (N, Lo), success (N,)); Lo = Lc + Le - 1 padded to 128.
@@ -83,20 +66,21 @@ def pmf_conv_pallas(pet: jnp.ndarray, pct: jnp.ndarray, dl: jnp.ndarray,
     lo = ((lo_true + 127) // 128) * 128
     block_n = min(block_n, n)
     pad_n = (-n) % block_n
+    # the PCT rides on the (lane-aligned) output grid, so the kernel never
+    # concatenates at an unaligned lane offset
+    pct = jnp.pad(pct, ((0, pad_n), (0, lo - lc)))
     if pad_n:
         pet = jnp.pad(pet, ((0, pad_n), (0, 0)))
-        pct = jnp.pad(pct, ((0, pad_n), (0, 0)))
         dl = jnp.pad(dl, (0, pad_n))
     nn = pet.shape[0]
     dl2 = dl.astype(jnp.float32)[:, None]
 
-    kernel = functools.partial(_kernel, le=le, lc=lc, lo=lo)
     out, suc = pl.pallas_call(
-        kernel,
+        _kernel,
         grid=(nn // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, le), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, lc), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, lo), lambda i: (i, 0)),
             pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_specs=[

@@ -22,7 +22,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x, scale, eps: float = 1e-5, block_rows: int = 256,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """x (..., D), scale (D,) -> same shape/dtype as x."""
     orig_shape = x.shape
     d = x.shape[-1]

@@ -4,6 +4,12 @@
         --requests 100 --merging adaptive --pruning --heuristic EDF \
         --planes 2 --router affinity --autoscale success-chance
 
+The model is served at its published widths with seeded random weights;
+``--reduced`` swaps in the toy-width, two-layer variant that CPU runs and
+the tests use.  ``--max-len`` bounds prompt plus generated tokens and sizes
+each unit's paged KV arena.  ``run(argv)`` is the same entry point as a
+function returning the summary dict; ``main`` prints it as JSON.
+
 ``--planes N`` shards the engine into N planes behind a ``Router``
 (``--router`` picks the policy); the JSON summary carries the aggregate,
 per-plane stats (hits, merges, drops, deadlock_breaks) and the routing
@@ -59,6 +65,7 @@ import numpy as np
 from ..configs.registry import get_arch
 from ..core.fleet import FleetSpec
 from ..core.pruning import PruningConfig
+from .compile_cache import enable_compile_cache
 from ..models import transformer as T
 from ..obs import (SCHEMA_VERSION, FlightRecorder, KernelProfiler,
                    SLOMonitor, Telemetry, install, write_chrome_trace,
@@ -85,9 +92,16 @@ def synth_trace(n: int, vocab: int, n_prompts: int = 8, rate: float = 0.2,
     return trace
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the toy-width two-layer variant of --arch "
+                         "(CPU runs and tests) instead of its published "
+                         "widths")
+    ap.add_argument("--max-len", type=int, default=64,
+                    help="tokens per sequence (prompt + generated); sizes "
+                         "every unit's KV arena")
     ap.add_argument("--requests", type=int, default=100)
     ap.add_argument("--units", type=int, default=2)
     ap.add_argument("--fleet", default=None,
@@ -150,9 +164,26 @@ def main():
                          "snapshots + kernel profile; DESIGN.md §2.12)")
     ap.add_argument("--record-capacity", type=int, default=65536,
                     help="flight-recorder ring size in events")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    cfg = get_arch(args.arch).reduced().scaled(n_layers=2, remat=False)
+
+def serve_config(args: argparse.Namespace):
+    """The served model configuration: published widths unless
+    ``--reduced``; no rematerialisation (serving runs no backward pass)."""
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced().scaled(n_layers=2)
+    return cfg.scaled(remat=False)
+
+
+def run(argv=None, trace=None) -> dict:
+    """Build the cluster from ``argv`` and serve; returns the summary dict.
+
+    ``trace`` — ``(arrival, Request)`` pairs — replaces the synthetic
+    open-loop trace when given."""
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg = serve_config(args)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     fleet = FleetSpec.parse(args.fleet) if args.fleet else None
     ecfg = EngineConfig(
@@ -168,7 +199,7 @@ def main():
             max_batch=args.max_batch,
             step_token_budget=args.step_token_budget)
         if args.max_batch > 1 else None,
-        max_len=64)
+        max_len=args.max_len)
     planes = make_engine_planes(cfg, params, ecfg, args.planes)
     autoscale = plane_factory = None
     if args.extra_planes > 0:
@@ -234,8 +265,9 @@ def main():
         workload = pool.summary()
         stats["workload"] = workload
     else:
-        trace = synth_trace(args.requests, cfg.vocab, rate=args.rate,
-                            deadline=args.deadline)
+        if trace is None:
+            trace = synth_trace(args.requests, cfg.vocab, rate=args.rate,
+                                deadline=args.deadline)
         stats = router.run(trace)
     if fleet is not None:
         stats["fleet"] = fleet.serialize()
@@ -274,7 +306,11 @@ def main():
     if args.events_out:
         write_jsonl(tel.events, args.events_out)
         stats["telemetry"]["events_out"] = args.events_out
-    print(json.dumps(stats, indent=2))
+    return stats
+
+
+def main():
+    print(json.dumps(run(), indent=2))
 
 
 if __name__ == "__main__":

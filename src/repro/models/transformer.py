@@ -16,6 +16,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..configs.base import ModelConfig
@@ -670,13 +671,27 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int):
     (kept host-side by the engine); ``chunk_prefill_fn`` output is written
     into pages and ``paged_decode_fn`` appends + attends through the
     tables.  Attention families only (dense/vlm).
+
+    Layout (L, NP, Hkv, PS, hd): the kv-head axis precedes the page slot,
+    so one page of one head is a contiguous (PS, hd) tile — the block the
+    paged decode kernel streams.
     """
     if cfg.family not in ("dense", "vlm"):
         raise ValueError(f"paged cache unsupported for family {cfg.family}")
     hd = cfg.resolved_head_dim
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, hd)
+    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, hd)
     return {"kp": jnp.zeros(shape, jnp.bfloat16),
             "vp": jnp.zeros(shape, jnp.bfloat16)}
+
+
+def kv_to_pages(kv: np.ndarray, page_size: int) -> np.ndarray:
+    """Host-side (L, S, Hkv, hd) prefill KV -> (L, ceil(S / PS), Hkv, PS, hd)
+    pages in the ``init_paged_cache`` layout, zero-padded to whole pages."""
+    n_pages = -(-kv.shape[1] // page_size)
+    pad = n_pages * page_size - kv.shape[1]
+    kv = np.pad(kv, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kv = kv.reshape((kv.shape[0], n_pages, page_size) + kv.shape[2:])
+    return kv.transpose(0, 1, 3, 2, 4)
 
 
 def chunk_prefill_fn(cfg: ModelConfig):
@@ -717,17 +732,18 @@ def chunk_prefill_fn(cfg: ModelConfig):
     return f
 
 
-def paged_decode_fn(cfg: ModelConfig):
+def paged_decode_fn(cfg: ModelConfig, use_kernel: bool | None = None):
     """Returns f(params, kp, vp, tables, lens, tokens) -> (logits, kp, vp).
 
     Batched single-step decode over the paged KV pool: ``tokens`` (B,) are
     the latest tokens of B independent sequences, ``tables`` (B, MP) their
-    page tables into the (L, NP, PS, Hkv, hd) pools and ``lens`` (B,)
+    page tables into the (L, NP, Hkv, PS, hd) pools and ``lens`` (B,)
     their context lengths.  Each step RoPEs/projects the B tokens, writes
     the new KV into page ``tables[b, len // PS]`` slot ``len % PS`` and
     attends through the block tables (``paged_decode_attention``), so all
     active sequences decode in one batched launch regardless of where
-    their KV lives.
+    their KV lives.  ``use_kernel`` is passed to that front door (None:
+    the Pallas kernel on an accelerator, the jnp oracle elsewhere).
     """
     fam = cfg.family
     if fam not in ("dense", "vlm"):
@@ -741,7 +757,7 @@ def paged_decode_fn(cfg: ModelConfig):
     def f(params, kp, vp, tables, lens, tokens):
         x = embed(params["embed"], tokens[:, None])          # (B, 1, D)
         b = x.shape[0]
-        ps = kp.shape[2]
+        ps = kp.shape[3]
         positions = lens[:, None]
         rows = jnp.arange(b)
         page = tables[rows, lens // ps]                      # (B,)
@@ -755,9 +771,10 @@ def paged_decode_fn(cfg: ModelConfig):
                                .reshape(b, 1, hkv, hd), positions,
                                cfg.rope_theta)
             v_new = dense(lp["attn"]["wv"], xn).reshape(b, 1, hkv, hd)
-            kc = kc.at[page, slot].set(k_new[:, 0].astype(kc.dtype))
-            vc = vc.at[page, slot].set(v_new[:, 0].astype(vc.dtype))
-            a = paged_decode_attention(q[:, 0], kc, vc, tables, lens + 1)
+            kc = kc.at[page, :, slot].set(k_new[:, 0].astype(kc.dtype))
+            vc = vc.at[page, :, slot].set(v_new[:, 0].astype(vc.dtype))
+            a = paged_decode_attention(q[:, 0], kc, vc, tables, lens + 1,
+                                       use_kernel=use_kernel)
             h = h + dense(lp["attn"]["wo"], a.reshape(b, 1, h_ * hd))
             h = h + mlp_apply(lp["mlp"], rmsnorm(lp["ln2"], h))
             return h, kc, vc

@@ -66,14 +66,13 @@ class KernelProfiler:
         key = (name, _shape_key(args, kwargs))
         cold = key not in self._seen
         self._seen.add(key)
+        import jax
+
         t0 = time.perf_counter()
         out = fn(*args, **kwargs)
         t1 = time.perf_counter()
-        try:
-            import jax
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        # a device fault surfaces here and propagates to the caller
+        jax.block_until_ready(out)
         t2 = time.perf_counter()
         rec = {"kernel": name, "dispatch_s": t1 - t0,
                "execute_s": t2 - t1, "cold": cold}

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -53,8 +52,8 @@ def compressed_psum_grads(grads, residual, mesh: Mesh, axis: str = "data"):
             return mean, new_r
 
         spec = P()  # grads replicated across the axis (pure DP replica view)
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                         out_specs=(spec, spec), check_rep=False)(g, r)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=(spec, spec), check_vma=False)(g, r)
 
     flat_g, tree = jax.tree_util.tree_flatten(grads)
     flat_r = tree.flatten_up_to(residual)

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -71,8 +70,8 @@ def pipeline_apply(block_fn, params_stacked, x_microbatches, mesh: Mesh,
 
     in_specs = (jax.tree.map(lambda _: P(stage_axis), params_stacked),
                 P())
-    return shard_map(staged, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(params_stacked, x_microbatches)
+    return jax.shard_map(staged, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(params_stacked, x_microbatches)
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

@@ -6,7 +6,8 @@ success probability — not raw queue depth — when deciding how aggressively
 to spend resources.  ``batch_chances`` is that signal for one scaling
 decision: every queued task's probability of meeting its deadline given
 the machine pool as it stands, evaluated in a single batched ``pmf_conv``
-launch (interpret-mode Pallas) so the controller's overhead stays
+launch (the Pallas kernel, compiled natively on a TPU and interpreted only
+where JAX has no accelerator) so the controller's overhead stays
 amortized per mapping event, with a pure-NumPy ``chance_of_success`` path
 as the fallback (companion-survey framing: keep the control loop's
 success-probability evaluation approximate and cheap).

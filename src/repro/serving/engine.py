@@ -546,18 +546,11 @@ class _UnitRunner:
         vv = np.concatenate(st["v"], axis=1)
         st["k"], st["v"] = [kk], [vv]
         npg = -(-s.plen // self.ps)
-        pad = npg * self.ps - s.plen
-        if pad:
-            z = np.zeros(kk.shape[:1] + (pad,) + kk.shape[2:], kk.dtype)
-            kk = np.concatenate([kk, z], axis=1)
-            vv = np.concatenate([vv, z], axis=1)
-        shape = (kk.shape[0], npg, self.ps) + kk.shape[2:]
         pids = jnp.asarray(st["pids"][:npg], jnp.int32)
         self.pages = {
-            "kp": self.pages["kp"].at[:, pids].set(
-                jnp.asarray(kk.reshape(shape), self.pages["kp"].dtype)),
-            "vp": self.pages["vp"].at[:, pids].set(
-                jnp.asarray(vv.reshape(shape), self.pages["vp"].dtype))}
+            name: self.pages[name].at[:, pids].set(jnp.asarray(
+                T.kv_to_pages(x, self.ps), self.pages[name].dtype))
+            for name, x in (("kp", kk), ("vp", vv))}
         st["len"] = s.plen
 
     # -- completion -----------------------------------------------------------

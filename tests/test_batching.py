@@ -135,8 +135,8 @@ class TestPagedDecodeKernel:
         ks = jax.random.split(jax.random.PRNGKey(seed), 4)
         n_pages = n_pages or (b * mp + 1)
         q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
-        kp = jax.random.normal(ks[1], (n_pages, ps, hkv, hd), jnp.float32)
-        vp = jax.random.normal(ks[2], (n_pages, ps, hkv, hd), jnp.float32)
+        kp = jax.random.normal(ks[1], (n_pages, hkv, ps, hd), jnp.float32)
+        vp = jax.random.normal(ks[2], (n_pages, hkv, ps, hd), jnp.float32)
         # disjoint per-sequence tables over a shuffled page pool
         perm = np.asarray(
             jax.random.permutation(ks[3], n_pages - 1)) + 1
@@ -187,8 +187,8 @@ class TestPagedDecodeKernel:
         kp2 = kp.at[tables[0, 1:]].set(99.0).at[tables[1, 2:]].set(99.0)
         vp2 = vp.at[tables[0, 1:]].set(-99.0).at[tables[1, 2:]].set(-99.0)
         # ... and the in-page tail of the last valid page
-        kp2 = kp2.at[tables[0, 0], 5:].set(99.0)
-        vp2 = vp2.at[tables[0, 0], 5:].set(-99.0)
+        kp2 = kp2.at[tables[0, 0], :, 5:].set(99.0)
+        vp2 = vp2.at[tables[0, 0], :, 5:].set(-99.0)
         out2 = paged_decode_attention(q, kp2, vp2, tables, lengths,
                                       interpret=True, use_kernel=True)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
@@ -212,6 +212,50 @@ class TestPagedDecodeKernel:
         ref = paged_decode_attention_ref(q, kp, vp, tables, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5, rtol=3e-5)
+
+
+def test_paged_decode_step_kernel_matches_oracle(tiny_model):
+    """One whole ``paged_decode_fn`` step over a head-major arena: the
+    interpreted kernel and the jnp oracle write the same new KV into the
+    same page slots and give the same logits."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import transformer as T
+    cfg, params = tiny_model
+    b, mp, ps = 3, 4, 8
+    arena = T.init_paged_cache(cfg, b * mp + 1, ps)
+    assert arena["kp"].shape == (cfg.n_layers, b * mp + 1, cfg.n_kv_heads,
+                                 ps, cfg.resolved_head_dim)
+    # prefill KV (L, S, Hkv, hd) lands token s at page s // ps, slot s % ps
+    kv = np.arange(cfg.n_layers * 11 * cfg.n_kv_heads * 2,
+                   dtype=np.float32).reshape(cfg.n_layers, 11,
+                                             cfg.n_kv_heads, 2)
+    pages = T.kv_to_pages(kv, ps)
+    assert pages.shape == (cfg.n_layers, 2, cfg.n_kv_heads, ps, 2)
+    np.testing.assert_array_equal(pages[:, 1, :, 10 - ps], kv[:, 10])
+    assert not pages[:, 1, :, 11 - ps:].any()
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    kp = jax.random.normal(ks[0], arena["kp"].shape, jnp.bfloat16)
+    vp = jax.random.normal(ks[1], arena["vp"].shape, jnp.bfloat16)
+    tables = jnp.arange(1, b * mp + 1, dtype=jnp.int32).reshape(b, mp)
+    lens = jnp.asarray([0, 9, mp * ps - 1], jnp.int32)
+    toks = jnp.asarray([5, 17, 99], jnp.int32)
+    outs = [jax.jit(T.paged_decode_fn(cfg, use_kernel=k))(
+        params, kp, vp, tables, lens, toks) for k in (True, False)]
+    (lk, kk, vk), (lr, kr, vr) = outs
+    np.testing.assert_array_equal(np.asarray(kk, np.float32),
+                                  np.asarray(kr, np.float32))
+    np.testing.assert_array_equal(np.asarray(vk, np.float32),
+                                  np.asarray(vr, np.float32))
+    # the new token's KV landed in page tables[b, len // ps], slot len % ps
+    page, slot = tables[1, 9 // ps], 9 % ps
+    assert not np.array_equal(np.asarray(kk[0, page, :, slot], np.float32),
+                              np.asarray(kp[0, page, :, slot], np.float32))
+    # the kernel keeps scores and probabilities in f32 where the oracle
+    # rounds them to bf16 (relative step 2**-8): logits differ by about 1%
+    # of their range; a wrong page, slot or head moves them by all of it
+    lk, lr = np.asarray(lk), np.asarray(lr)
+    assert np.abs(lk - lr).max() <= 3e-2 * np.abs(lr).max()
 
 
 class TestBlockTuning:

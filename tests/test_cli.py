@@ -8,6 +8,8 @@ import sys
 SRC = os.path.join(os.path.dirname(__file__), os.pardir, "src")
 ROOT = os.path.join(os.path.dirname(__file__), os.pardir)
 ENV = dict(os.environ, PYTHONPATH=SRC)
+# the CPU suite serves the toy-width model
+SERVE = ["repro.launch.serve", "--reduced"]
 
 
 def _run(args, timeout=900, env=ENV):
@@ -30,41 +32,41 @@ def test_train_cli_reduced(tmp_path):
 
 
 def test_serve_cli(tmp_path):
-    out = _run(["repro.launch.serve", "--requests", "12", "--units", "1",
-                "--merging", "adaptive", "--pruning", "--rate", "0.5"])
+    out = _run(SERVE + ["--requests", "12", "--units", "1",
+                        "--merging", "adaptive", "--pruning", "--rate", "0.5"])
     assert '"completed"' in out
 
 
 def test_serve_cli_batching():
-    out = _run(["repro.launch.serve", "--requests", "10", "--units", "1",
-                "--rate", "0.5", "--max-batch", "4",
-                "--step-token-budget", "32"])
+    out = _run(SERVE + ["--requests", "10", "--units", "1",
+                        "--rate", "0.5", "--max-batch", "4",
+                        "--step-token-budget", "32"])
     # the batching knobs are echoed back in the JSON summary
     assert '"max_batch": 4' in out and '"step_token_budget": 32' in out
     assert '"completed"' in out
 
 
 def test_serve_cli_autoscale():
-    out = _run(["repro.launch.serve", "--requests", "10", "--units", "1",
-                "--rate", "0.5", "--autoscale", "success-chance",
-                "--max-extra-units", "1"])
+    out = _run(SERVE + ["--requests", "10", "--units", "1",
+                        "--rate", "0.5", "--autoscale", "success-chance",
+                        "--max-extra-units", "1"])
     # the autoscale decision counters ride in the JSON summary
     assert '"scale_ups"' in out and '"machine_seconds"' in out
     assert '"warmup_ticks"' in out
 
 
 def test_serve_cli_fleet():
-    out = _run(["repro.launch.serve", "--requests", "8", "--rate", "0.5",
-                "--fleet", "tpu:1:1.0:1.0,cpu:1:0.5:0.25",
-                "--heuristic", "MCMD", "--max-extra-units", "0"])
+    out = _run(SERVE + ["--requests", "8", "--rate", "0.5",
+                        "--fleet", "tpu:1:1.0:1.0,cpu:1:0.5:0.25",
+                        "--heuristic", "MCMD", "--max-extra-units", "0"])
     # the fleet spec and the per-mtype cost counters ride in the summary
     assert '"fleet": "tpu:1:1:1:auto:4,cpu:1:0.5:0.25:auto:4"' in out
     assert '"cost"' in out and '"pool_cost"' in out
 
 
 def test_serve_cli_multiplane():
-    out = _run(["repro.launch.serve", "--requests", "10", "--units", "1",
-                "--planes", "2", "--router", "affinity", "--rate", "0.5"])
+    out = _run(SERVE + ["--requests", "10", "--units", "1", "--planes", "2",
+                        "--router", "affinity", "--rate", "0.5"])
     assert '"completed"' in out
     # per-plane stats + routing counters ride in the JSON summary
     assert '"planes"' in out and '"router"' in out
@@ -81,10 +83,11 @@ def test_serve_cli_telemetry_out(tmp_path):
     trace = tmp_path / "trace.json"
     metrics = tmp_path / "metrics.json"
     events = tmp_path / "events.jsonl"
-    out = _run(["repro.launch.serve", "--requests", "10", "--units", "1",
-                "--merging", "adaptive", "--pruning", "--rate", "0.5",
-                "--trace-out", str(trace), "--metrics-out", str(metrics),
-                "--events-out", str(events)])
+    out = _run(SERVE + ["--requests", "10", "--units", "1",
+                        "--merging", "adaptive", "--pruning", "--rate", "0.5",
+                        "--trace-out", str(trace),
+                        "--metrics-out", str(metrics),
+                        "--events-out", str(events)])
     stats = json.loads(out)
     tel = stats["telemetry"]
     assert tel["schema"] == SCHEMA_VERSION
@@ -107,9 +110,9 @@ def test_serve_cli_closed_loop():
     consolidated telemetry validates against the current schema."""
     from repro.obs import validate_telemetry_summary
 
-    out = _run(["repro.launch.serve", "--workload", "closed_loop:6:2",
-                "--turns", "3", "--tenants", "gold:1:0.5:1,free:3",
-                "--units", "1", "--rate", "0.5"])
+    out = _run(SERVE + ["--workload", "closed_loop:6:2",
+                        "--turns", "3", "--tenants", "gold:1:0.5:1,free:3",
+                        "--units", "1", "--rate", "0.5"])
     stats = json.loads(out)
     wl = stats["workload"]
     assert wl["mode"] == "closed_loop"
@@ -177,3 +180,42 @@ def test_dryrun_cli_tiny_decode():
         capture_output=True, text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stderr[-1500:]
     assert "roofline" in out.stdout
+
+
+def test_serve_config_widths():
+    """The serve entry point runs the published widths unless --reduced."""
+    from repro.configs.registry import get_arch
+    from repro.launch.serve import parse_args, serve_config
+
+    full = serve_config(parse_args(["--arch", "smollm-360m"]))
+    published = get_arch("smollm-360m")
+    assert (full.n_layers, full.d_model, full.n_heads, full.n_kv_heads,
+            full.d_ff, full.vocab) == (published.n_layers, published.d_model,
+                                       published.n_heads, published.n_kv_heads,
+                                       published.d_ff, published.vocab)
+    assert not full.remat
+    toy = serve_config(parse_args(["--arch", "smollm-360m", "--reduced"]))
+    assert toy.n_layers == 2 and toy.d_model < published.d_model
+    args = parse_args(["--max-len", "2048"])
+    assert args.max_len == 2048 and parse_args([]).max_len == 64
+
+
+def test_compile_cache_dir(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache goes to a fixed, git-ignored directory inside the checkout."""
+    import jax
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(os.path.abspath(ROOT), ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        ignored = open(os.path.join(ROOT, ".gitignore")).read().split()
+        assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps + hypothesis property tests,
-all against the pure-jnp ref oracles, executed with interpret=True."""
+all against the pure-jnp ref oracles.  Off an accelerator the front
+doors resolve ``interpret=None`` to the Pallas interpreter."""
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from repro.core.pmf import PMF, chance_of_success
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.pmf_conv.ops import batched_success, pmf_conv
+from repro.kernels.pmf_conv.pmf_conv import pmf_conv_pallas
 from repro.kernels.pmf_conv.ref import pmf_conv_ref
 from repro.kernels.rmsnorm.ops import rmsnorm
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
@@ -68,6 +70,54 @@ class TestPmfConv:
         want = [chance_of_success(e, c, dl, droppable_prev=True)
                 for e, c, dl in zip(pets, pcts, dls)]
         np.testing.assert_allclose(got, want, atol=1e-5)
+
+    def test_success_exact_when_first_pet_impulse_has_mass(self):
+        """PETs clamped at t=1 carry real mass on their first impulse; the
+        previous task may then free the machine exactly at dl - 1 and the
+        kernel path must count it, as ``chance_of_success`` does."""
+        rng = np.random.default_rng(7)
+        pets = [PMF.from_normal(rng.uniform(3, 6), rng.uniform(1, 2))
+                for _ in range(32)]
+        pcts = [PMF.from_normal(rng.uniform(5, 30), rng.uniform(1, 5))
+                for _ in range(32)]
+        dls = [int(e.mean() + c.mean() + rng.integers(-8, 12))
+               for e, c in zip(pets, pcts)]
+        assert all(p.offset == 1 for p in pets)
+        got = batched_success(pets, pcts, dls, length=64)
+        want = [chance_of_success(e, c, dl, droppable_prev=True)
+                for e, c, dl in zip(pets, pcts, dls)]
+        np.testing.assert_allclose(got, want, atol=1e-6)
+
+    def test_rolled_kernel_matches_ref_at_autoscaler_grid(self):
+        """The lane-rotate formulation at the success-chance autoscaler's
+        (32, 64) grid, with deadlines before, inside and past the grid —
+        every shift 0..63 runs, so a wrong rotate direction cannot pass."""
+        pet, pct, _ = self._data(32, 64, 64, seed=11)
+        dl = jnp.asarray(np.linspace(-1, 140, 32).round(), jnp.float32)
+        out_k, suc_k = pmf_conv_pallas(pet, pct, dl, interpret=True)
+        out_r, suc_r = pmf_conv_ref(pet, pct, dl)
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                                   atol=1e-6, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(suc_k), np.asarray(suc_r),
+                                   atol=1e-6, rtol=1e-5)
+
+    def test_interpret_none_resolves_through_default(self, monkeypatch):
+        from repro.kernels.pmf_conv import ops as pmf_ops
+        calls = []
+
+        def default():
+            calls.append(1)
+            return True         # no accelerator here: interpret
+
+        monkeypatch.setattr(pmf_ops, "interpret_default", default)
+        pet, pct, dl = self._data(4, 8, 16)
+        out, suc = pmf_conv(pet, pct, dl)
+        assert calls == [1]
+        np.testing.assert_allclose(np.asarray(suc),
+                                   np.asarray(pmf_conv_ref(pet, pct, dl)[1]),
+                                   atol=1e-6, rtol=1e-5)
+        batched_success([PMF.impulse(3)], [PMF.impulse(2)], [9], length=16)
+        assert calls == [1, 1]
 
     @settings(max_examples=20, deadline=None)
     @given(st.integers(1, 12), st.integers(2, 24), st.integers(2, 48),
