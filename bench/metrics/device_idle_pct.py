@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device-operation intervals) / window, from the profiler
+trace."""
+
+
+def read(run):
+    tr = run["trace"]
+    if tr is None or tr["busy_s"] <= 0 or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
