@@ -393,19 +393,47 @@ class ControlPlane:
     def _mapping_event(self) -> None:
         self.sub.before_mapping(self.now)
         # the overhead clock covers *scheduling* only: elasticity above and
-        # machine starts below run substrate code (compiles, model steps)
+        # machine starts below run substrate code (compiles, model steps);
+        # the ``cp.map`` span and the counter share their clock reads
         t0 = time.perf_counter()
+        with self.tel.span("cp.map", start=t0, plane=self.plane_id) as sp:
+            self._map()
+            t1 = time.perf_counter()
+            sp.stop(t1)
+        dt = t1 - t0
+        self.stats["mapping_wall_s"] += dt
+        self.tel.metrics.inc("mapping_wall_s_total", dt)
+        self.tel.metrics.observe("mapping_event_wall_s", dt)
+        self.tel.metrics.gauge("pruning_wall_s", self.stats["pruning_wall_s"])
+        # start idle machines (execution time is the substrate's, not ours)
+        for m in self.sub.machines:
+            if m.max_batch > 1:
+                self._start_batched(m)
+            elif m.running is None and m.queue:
+                self._start_next(m)
+        if self.after_mapping is not None:
+            self.after_mapping(self)
+
+    def _pruning(self, fn, *args):
+        """Run one pruner pass under a ``cp.prune`` span; its wall time is
+        the mechanism's own overhead (§5.5), attributed apart."""
+        tp0 = time.perf_counter()
+        with self.tel.span("cp.prune", start=tp0) as sp:
+            out = fn(*args)
+            tp1 = time.perf_counter()
+            sp.stop(tp1)
+        self.stats["pruning_wall_s"] += tp1 - tp0
+        return out
+
+    def _map(self) -> None:
         machines = self.sub.machines
         self.stats["mapping_events"] += 1
         if self.cfg.hard_deadlines:
             self._purge_infeasible()
         if self.pruner is not None:
-            # pruner dropping pass over machine queues (Fig. 5.5); its wall
-            # time is the mechanism's own overhead (§5.5), attributed apart
-            tp0 = time.perf_counter()
-            dropped = self.pruner.drop_pass(machines, self.now,
-                                            self._misses_since_event)
-            self.stats["pruning_wall_s"] += time.perf_counter() - tp0
+            # pruner dropping pass over machine queues (Fig. 5.5)
+            dropped = self._pruning(self.pruner.drop_pass, machines,
+                                    self.now, self._misses_since_event)
             self._misses_since_event = 0
             for t in dropped:
                 self._evict_if_running(t, machines)
@@ -430,10 +458,8 @@ class ControlPlane:
                     and self.heuristic.name not in ("PAM", "PAMF")):
                 # Eq. 5.10 estimator runs every mapping event regardless of
                 # the plugged-in heuristic (Fig. 5.5)
-                tp0 = time.perf_counter()
-                self.pruner.refresh_defer_threshold(
-                    self.batch, machines, ctx.chance, self.now)
-                self.stats["pruning_wall_s"] += time.perf_counter() - tp0
+                self._pruning(self.pruner.refresh_defer_threshold,
+                              self.batch, machines, ctx.chance, self.now)
             before_defer = self.pruner.stats["deferred"] if self.pruner else 0
             if self.pruner is not None:
                 self.pruner.defer_log.clear()
@@ -458,19 +484,6 @@ class ControlPlane:
                     self._log("map", self._index(t), machines.index(m))
                     self.tel.event(self.now, "map", task=self._index(t),
                                    machine=m.mid, plane=self.plane_id)
-        dt = time.perf_counter() - t0
-        self.stats["mapping_wall_s"] += dt
-        self.tel.metrics.inc("mapping_wall_s_total", dt)
-        self.tel.metrics.observe("mapping_event_wall_s", dt)
-        self.tel.metrics.gauge("pruning_wall_s", self.stats["pruning_wall_s"])
-        # start idle machines (execution time is the substrate's, not ours)
-        for m in machines:
-            if m.max_batch > 1:
-                self._start_batched(m)
-            elif m.running is None and m.queue:
-                self._start_next(m)
-        if self.after_mapping is not None:
-            self.after_mapping(self)
 
     def _purge_infeasible(self) -> None:
         live, dead = [], []

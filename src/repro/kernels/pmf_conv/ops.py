@@ -32,10 +32,10 @@ def pmf_conv(pet, pct, dl, interpret: bool | None = None,
 
     ``interpret=None`` resolves through ``interpret_default()``: native on
     a TPU/GPU, the Pallas interpreter only where JAX has no accelerator.
-    Launches route through ``repro.obs.profiling`` — a zero-cost
-    passthrough unless a ``KernelProfiler`` is installed, which then
-    splits dispatch (trace/compile) from execute (``block_until_ready``)
-    per launch."""
+    Launches route through ``repro.obs.profiling.profiled`` — a plain
+    call unless a recorder is installed (``repro.obs.install``), whose
+    ``kernel.pmf_conv`` span then splits dispatch (trace/compile) from
+    execute (``block_until_ready``) per launch."""
     if interpret is None:
         interpret = interpret_default()
     return profiled("pmf_conv", _pmf_conv_jit, pet, pct, dl,

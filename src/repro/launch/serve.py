@@ -47,8 +47,9 @@ rides under ``telemetry.slo``.
 
 ``--record-out FILE`` swaps the telemetry recorder for a flight recorder
 (DESIGN.md §2.12): the bounded event ring, every arrival payload,
-periodic ``TimeEstimator`` EWMA snapshots and the kernel-profiler
-compile/execute split are serialized into one replayable artifact —
+periodic ``TimeEstimator`` EWMA snapshots and the dispatch/execute split
+of eager kernel launches (the recorder is installed for ``profiled``
+launches) are serialized into one replayable artifact —
 ``obs.fit.fit_oracle`` turns it into a measured oracle and
 ``obs.replay.drift_report`` re-drives it through the simulator.
 """
@@ -67,9 +68,8 @@ from ..core.fleet import FleetSpec
 from ..core.pruning import PruningConfig
 from .compile_cache import enable_compile_cache
 from ..models import transformer as T
-from ..obs import (SCHEMA_VERSION, FlightRecorder, KernelProfiler,
-                   SLOMonitor, Telemetry, install, write_chrome_trace,
-                   write_jsonl, write_metrics)
+from ..obs import (SCHEMA_VERSION, FlightRecorder, SLOMonitor, Telemetry,
+                   install, write_chrome_trace, write_jsonl, write_metrics)
 from ..serving.autoscale import SCALER_POLICIES, ElasticityConfig
 from ..serving.batching import StepBatchingConfig
 from ..serving.cluster import (ROUTER_POLICIES, Router,
@@ -221,9 +221,7 @@ def run(argv=None, trace=None) -> dict:
         recorder.note_engine_config(ecfg)
         recorder.meta.update({"arch": args.arch, "planes": args.planes,
                               "time_scale": float(TICKS_PER_SEC)})
-        profiler = KernelProfiler(metrics=tel.metrics)
-        install(profiler)
-        recorder.use_profiler(profiler)
+        install(recorder)
     else:
         tel = Telemetry(wall_clock=time.perf_counter)
     router = Router(planes, policy=args.router, autoscale=autoscale,
@@ -294,11 +292,14 @@ def run(argv=None, trace=None) -> dict:
         recorder.snapshot_estimator(now, planes[0].sub.estimator)
         recorder.note_machines([m for p in planes for m in p.sub.machines])
         recorder.note_stats(stats)
+        install(None)
         recorder.save(args.record_out)
         stats["telemetry"]["record_out"] = args.record_out
     if args.trace_out:
-        write_chrome_trace(tel.events, args.trace_out,
-                           us_per_unit=1e6 / TICKS_PER_SEC)
+        # the live engine's timeline is measured: events and spans on the
+        # wall clock, not the engine's modelled ticks
+        write_chrome_trace(tel.events, args.trace_out, clock="wall",
+                           spans=tel.spans)
         stats["telemetry"]["trace_out"] = args.trace_out
     if args.metrics_out:
         write_metrics(tel.metrics, args.metrics_out)

@@ -704,13 +704,14 @@ def chunk_prefill_fn(cfg: ModelConfig):
     unpadded, resumable core).  Returns last-position logits plus the KV
     of *only the new chunk* (L, B, C, Hkv, hd) so the caller can append it
     to paged storage and feed it back as prefix for the next chunk.
-    P=0 reduces to a cold prefill of the first chunk.
+    P=0 reduces to a cold prefill of the first chunk.  Jitted, the program
+    is named ``jit_chunk_prefill`` on the device.
     """
     fam = cfg.family
     if fam not in ("dense", "vlm"):
         raise ValueError(f"chunked prefill unsupported for family {fam}")
 
-    def f(params, tokens, prefix_k, prefix_v):
+    def chunk_prefill(params, tokens, prefix_k, prefix_v):
         x = embed(params["embed"], tokens)
         p_len = prefix_k.shape[2]
 
@@ -729,7 +730,7 @@ def chunk_prefill_fn(cfg: ModelConfig):
         logits = hidden[:, -1] @ _unembed_matrix(cfg, params)
         # attention_apply returns full-context KV; keep only the new chunk
         return logits.astype(jnp.float32), ks_[:, :, p_len:], vs_[:, :, p_len:]
-    return f
+    return chunk_prefill
 
 
 def paged_decode_fn(cfg: ModelConfig, use_kernel: bool | None = None):
@@ -744,6 +745,7 @@ def paged_decode_fn(cfg: ModelConfig, use_kernel: bool | None = None):
     active sequences decode in one batched launch regardless of where
     their KV lives.  ``use_kernel`` is passed to that front door (None:
     the Pallas kernel on an accelerator, the jnp oracle elsewhere).
+    Jitted, the program is named ``jit_paged_decode_step`` on the device.
     """
     fam = cfg.family
     if fam not in ("dense", "vlm"):
@@ -754,7 +756,7 @@ def paged_decode_fn(cfg: ModelConfig, use_kernel: bool | None = None):
     hd = cfg.resolved_head_dim
     h_, hkv = cfg.n_heads, cfg.n_kv_heads
 
-    def f(params, kp, vp, tables, lens, tokens):
+    def paged_decode_step(params, kp, vp, tables, lens, tokens):
         x = embed(params["embed"], tokens[:, None])          # (B, 1, D)
         b = x.shape[0]
         ps = kp.shape[3]
@@ -793,7 +795,7 @@ def paged_decode_fn(cfg: ModelConfig, use_kernel: bool | None = None):
         h = rmsnorm(params["final_ln"], h)
         logits = (h[:, 0] @ _unembed_matrix(cfg, params)).astype(jnp.float32)
         return logits, kp, vp
-    return f
+    return paged_decode_step
 
 
 def prefill_from_cache(cfg: ModelConfig):

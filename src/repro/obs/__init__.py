@@ -1,6 +1,7 @@
-"""Observability layer: telemetry recorder, metrics, exporters, profiling,
-and the closed loop on top of them — flight recorder, telemetry-fitted
-oracles, replay drift audits, per-tenant SLO burn-rate monitors.
+"""Observability layer: telemetry recorder with wall-clock spans, metrics,
+exporters, kernel launches on the span API, and the closed loop on top of
+them — flight recorder, telemetry-fitted oracles, replay drift audits,
+per-tenant SLO burn-rate monitors.
 
 See DESIGN.md §2.9 and §2.12.  Import surface is dependency-free (stdlib
 only) so the pure-numpy simulation path can enable telemetry without JAX
@@ -11,7 +12,7 @@ from .metrics import MetricsRegistry, NullMetrics, StreamingHistogram
 from .telemetry import NULL, NullTelemetry, Telemetry
 from .exporters import (chrome_trace, parse_prometheus, write_chrome_trace,
                         write_jsonl, write_metrics)
-from .profiling import KernelProfiler, install, profiled
+from .profiling import install, profiled
 from .recorder import FlightRecorder, load_record
 from .fit import FittedOracle, fit_oracle, fit_table
 from .replay import drift_report, replay_record
@@ -26,7 +27,7 @@ __all__ = [
     "NULL", "NullTelemetry", "Telemetry",
     "chrome_trace", "parse_prometheus", "write_chrome_trace", "write_jsonl",
     "write_metrics",
-    "KernelProfiler", "install", "profiled",
+    "install", "profiled",
     "FlightRecorder", "load_record",
     "FittedOracle", "fit_oracle", "fit_table",
     "drift_report", "replay_record",

@@ -13,9 +13,16 @@ and chrome://tracing both render:
   * instant ``"i"`` events for control decisions (admit/merge/drop/defer/
     route/scale/kv), carrying reason and chance-of-success in ``args``.
 
-Timestamps: the trace-event ``ts`` unit is microseconds.  Virtual time
-(engine ticks or simulated seconds) is scaled by ``us_per_unit`` so both
-substrates produce overlay-comparable timelines.
+Timestamps: the trace-event ``ts`` unit is microseconds.  On the virtual
+clock (``clock="t"``, the default) engine ticks or simulated seconds are
+scaled by ``us_per_unit`` so both substrates produce overlay-comparable
+timelines.  On the wall clock (``clock="wall"``, the live engine) every
+event is placed by its ``wall`` stamp, and the recorder's spans
+(``Telemetry.spans``) join the trace: nested spans as ``"X"`` events on
+their unit's track (the ``machine`` of the span or of an enclosing one),
+the control-plane spans on the control track, and keyed spans (a
+request's phases) as async ``"b"``/``"e"`` pairs.  Spans carry no tick
+stamps, so the virtual clock leaves them out.
 """
 
 from __future__ import annotations
@@ -56,16 +63,61 @@ def _req_name(ev: dict) -> str:
     return f"{name} [{tenant}]" if tenant else name
 
 
-def chrome_trace(events, us_per_unit: float = 1e6) -> dict:
-    """Convert a telemetry event list into a Chrome trace-event object."""
+def _span_events(spans, origin: float, procs: set, threads: set):
+    """Trace events for the recorder's spans on the wall clock."""
+    by_id = {s["id"]: s for s in spans}
+    out: list[dict] = []
+    for s in spans:
+        args = {k: v for k, v in s.items()
+                if k not in ("name", "id", "parent", "start", "end", "keyed")}
+        ts, dur = (s["start"] - origin) * 1e6, (s["end"] - s["start"]) * 1e6
+        pid, tid, up = s.get("plane"), s.get("machine"), s
+        while (pid is None or tid is None) and up.get("parent") in by_id:
+            up = by_id[up["parent"]]
+            pid = up.get("plane") if pid is None else pid
+            tid = up.get("machine") if tid is None else tid
+        pid = int(pid or 0)
+        procs.add(pid)
+        if s.get("keyed"):
+            common = {"name": s["name"], "cat": "span", "id": s["id"],
+                      "pid": pid, "tid": _CONTROL_TID}
+            out.append({**common, "ph": "b", "ts": ts, "args": args})
+            out.append({**common, "ph": "e", "ts": ts + dur})
+            continue
+        tid = _CONTROL_TID if tid is None else int(tid)
+        if tid != _CONTROL_TID:
+            threads.add((pid, tid))
+        out.append({"name": s["name"], "ph": "X", "pid": pid, "tid": tid,
+                    "ts": ts, "dur": max(dur, 0.0), "cat": "span",
+                    "args": args})
+    return out
+
+
+def chrome_trace(events, us_per_unit: float = 1e6, clock: str = "t",
+                 spans=()) -> dict:
+    """Convert a telemetry event list (and, on the wall clock, the spans)
+    into a Chrome trace-event object."""
+    if clock not in ("t", "wall"):
+        raise ValueError(f"clock must be 't' or 'wall', not {clock!r}")
     trace: list[dict] = []
     procs: set[int] = set()
     threads: set[tuple[int, int]] = set()
     open_exec: dict = {}          # (plane, machine, req/task) -> start ev
     flow_id = 0                   # incrementing id shared by each s/f pair
+    spans = list(spans) if clock == "wall" else []
+    if clock == "wall":
+        missing = [e["kind"] for e in events if "wall" not in e]
+        if missing:
+            raise ValueError(f"{len(missing)} events carry no wall stamp "
+                             f"(first: {missing[0]!r})")
+        origin = min([e["wall"] for e in events]
+                     + [s["start"] for s in spans], default=0.0)
 
-    def ts(ev):
-        return ev["t"] * us_per_unit
+        def ts(ev):
+            return (ev["wall"] - origin) * 1e6
+    else:
+        def ts(ev):
+            return ev["t"] * us_per_unit
 
     for ev in events:
         pid = int(ev.get("plane", 0))
@@ -121,6 +173,8 @@ def chrome_trace(events, us_per_unit: float = 1e6) -> dict:
                 "cat": "decision", "args": _args(ev),
             })
 
+    if spans:
+        trace += _span_events(spans, origin, procs, threads)
     meta: list[dict] = []
     for pid in sorted(procs):
         meta.append({"name": "process_name", "ph": "M", "pid": pid,
@@ -134,9 +188,10 @@ def chrome_trace(events, us_per_unit: float = 1e6) -> dict:
     return {"traceEvents": meta + trace, "displayTimeUnit": "ms"}
 
 
-def write_chrome_trace(events, path, us_per_unit: float = 1e6) -> None:
+def write_chrome_trace(events, path, us_per_unit: float = 1e6,
+                       clock: str = "t", spans=()) -> None:
     with open(path, "w") as fh:
-        json.dump(chrome_trace(events, us_per_unit), fh)
+        json.dump(chrome_trace(events, us_per_unit, clock, spans), fh)
 
 
 def write_metrics(metrics, path) -> None:

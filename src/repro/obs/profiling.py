@@ -1,40 +1,48 @@
-"""Kernel-launch profiler — compile-vs-execute attribution (DESIGN.md §2.9).
+"""Kernel launches on the span API (DESIGN.md §2.9).
 
-The pruning chapter budgets the mechanism's *own* overhead; on the engine
-that overhead is dominated by the jitted kernel front doors (``pmf_conv``,
-``decode_attention``, ``rmsnorm``).  Each front door routes its call through
-:func:`profiled`, which is a zero-cost passthrough until a
-:class:`KernelProfiler` is installed via :func:`install`.
+Every kernel front door (``pmf_conv``, ``decode_attention``,
+``paged_decode_attention``, ``rmsnorm``) routes its call through
+:func:`profiled`:
 
-When active, a launch is split into
+  * called while JAX traces an enclosing program (some argument is a
+    tracer), it opens ``jax.named_scope(name)``: the kernel's operations
+    carry the name in their metadata, and nothing is timed, since tracing
+    is not execution;
+  * called eagerly with a recorder installed (:func:`install`), the launch
+    becomes a span ``kernel.<name>`` with children ``dispatch`` (until the
+    jitted call returns: tracing and compilation on a new shape) and
+    ``sync`` (until ``jax.block_until_ready`` returns: device execution),
+    both tagged ``program=<name>`` so that the recorder's ``programs``
+    sums them.  The first launch per (kernel, shape key) carries
+    ``cold=True``.  The engine's two step programs are launched by the
+    unit's own step loop, whose ``launch`` / ``sync`` spans carry their
+    program's name the same way;
+  * otherwise it is a plain call.
 
-  * ``dispatch_s`` — time to return from the jitted call (includes tracing
-    and XLA compilation on the first call for a given shape key), and
-  * ``execute_s`` — additional time until ``jax.block_until_ready`` returns
-    (device execution of the dispatched work).
-
-The first launch per (kernel, shape-key) is flagged ``cold`` — its
-dispatch time is dominated by compilation.  No JAX import happens at module
-scope, so the pure-numpy simulation path can import ``repro.obs`` freely.
+No JAX import happens at module scope, so the pure-numpy simulation path
+can import ``repro.obs`` freely.
 """
 
 from __future__ import annotations
 
-import time
+import sys
 
-__all__ = ["KernelProfiler", "install", "profiled", "current"]
+__all__ = ["install", "profiled", "current"]
 
-_PROFILER = None
+_TEL = None
+_SEEN: set = set()
 
 
-def install(profiler) -> None:
-    """Install (or with ``None``, remove) the process-wide profiler."""
-    global _PROFILER
-    _PROFILER = profiler
+def install(tel) -> None:
+    """Install (or with ``None``, remove) the process-wide recorder that
+    eager kernel launches report to."""
+    global _TEL
+    _TEL = tel
+    _SEEN.clear()
 
 
 def current():
-    return _PROFILER
+    return _TEL
 
 
 def _shape_key(args, kwargs) -> tuple:
@@ -51,55 +59,41 @@ def _shape_key(args, kwargs) -> tuple:
     return tuple(parts)
 
 
-class KernelProfiler:
-    """Records one dict per launch; aggregates into ``metrics`` when given
-    a registry (``kernel_dispatch_s`` / ``kernel_execute_s`` histograms
-    labeled by kernel name)."""
-
-    def __init__(self, metrics=None, telemetry=None):
-        self.records: list[dict] = []
-        self.metrics = metrics
-        self.telemetry = telemetry
-        self._seen: set = set()
-
-    def launch(self, name: str, fn, *args, **kwargs):
-        key = (name, _shape_key(args, kwargs))
-        cold = key not in self._seen
-        self._seen.add(key)
-        import jax
-
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        t1 = time.perf_counter()
-        # a device fault surfaces here and propagates to the caller
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        rec = {"kernel": name, "dispatch_s": t1 - t0,
-               "execute_s": t2 - t1, "cold": cold}
-        self.records.append(rec)
-        if self.metrics is not None:
-            self.metrics.observe("kernel_dispatch_s", rec["dispatch_s"],
-                                 kernel=name, cold=str(cold).lower())
-            self.metrics.observe("kernel_execute_s", rec["execute_s"],
-                                 kernel=name)
-            self.metrics.inc("kernel_launches", kernel=name)
-        return out
-
-    def summary(self) -> dict:
-        out: dict = {}
-        for r in self.records:
-            s = out.setdefault(r["kernel"], {
-                "launches": 0, "cold_launches": 0,
-                "dispatch_s": 0.0, "execute_s": 0.0})
-            s["launches"] += 1
-            s["cold_launches"] += int(r["cold"])
-            s["dispatch_s"] += r["dispatch_s"]
-            s["execute_s"] += r["execute_s"]
-        return out
+def _traced(args, kwargs) -> bool:
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    tracer = jax.core.Tracer
+    return any(isinstance(a, tracer) for a in args) or any(
+        isinstance(v, tracer) for v in kwargs.values())
 
 
 def profiled(name: str, fn, *args, **kwargs):
-    """Route a kernel launch through the installed profiler (if any)."""
-    if _PROFILER is None:
+    """Launch ``fn(*args, **kwargs)`` as the kernel ``name`` (see module
+    doc)."""
+    if _traced(args, kwargs):
+        import jax
+
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    tel = _TEL
+    if tel is None:
         return fn(*args, **kwargs)
-    return _PROFILER.launch(name, fn, *args, **kwargs)
+    import jax
+
+    key = (name, _shape_key(args, kwargs))
+    cold = key not in _SEEN
+    _SEEN.add(key)
+    with tel.span(f"kernel.{name}", cold=cold):
+        with tel.span("dispatch", program=name, cold=cold) as d:
+            out = fn(*args, **kwargs)
+        with tel.span("sync", program=name) as s:
+            # a device fault surfaces here and propagates to the caller
+            jax.block_until_ready(out)
+    if tel.enabled:
+        tel.metrics.observe("kernel_dispatch_s", d.duration, kernel=name,
+                            cold=str(cold).lower())
+        tel.metrics.observe("kernel_execute_s", s.duration, kernel=name)
+        tel.metrics.inc("kernel_launches", kernel=name)
+    return out
+

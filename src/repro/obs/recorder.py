@@ -9,7 +9,10 @@ replay needs:
     similarity keys, merge identities and deadlines;
   * periodic ``TimeEstimator`` EWMA snapshots (``watch_estimator`` /
     ``snapshot_estimator``) via the estimator's ``dump()``;
-  * kernel-profiler compile/execute splits (``use_profiler``);
+  * the dispatch/execute split of every launch, summed per program as its
+    spans are recorded (``Telemetry.programs``): the engine's step
+    programs, and eager kernel launches when this recorder is the
+    installed one;
   * the fleet table (``note_machines``), the control knobs
     (``note_engine_config``) and the run's final counters (``note_stats``)
     so a drift audit has ground truth to diff against.
@@ -79,7 +82,6 @@ class FlightRecorder(Telemetry):
         self.snapshot_interval = float(snapshot_interval)
         self._watched = None
         self._last_snap: float | None = None
-        self._profiler = None
 
     # -- event stream (ring) --------------------------------------------------
     def event(self, t: float, kind: str, **attrs) -> None:
@@ -110,10 +112,6 @@ class FlightRecorder(Telemetry):
         self._last_snap = t
         self.est_snapshots.append({"t": round(t, 6),
                                    "estimator": est.dump()})
-
-    def use_profiler(self, profiler) -> None:
-        """Reference a KernelProfiler whose records/summary ride along."""
-        self._profiler = profiler
 
     def note_machines(self, machines) -> None:
         """Record the fleet table (mids must survive into the replay so the
@@ -160,9 +158,11 @@ class FlightRecorder(Telemetry):
                "engine_config": dict(self.engine_config),
                "stats": dict(self.run_stats),
                "meta": dict(self.meta)}
-        if self._profiler is not None:
-            art["kernel"] = {"summary": self._profiler.summary(),
-                             "launches": len(self._profiler.records)}
+        if self.programs:
+            art["kernel"] = {"summary": {k: dict(v) for k, v
+                                         in self.programs.items()},
+                             "launches": sum(k["launches"] for k
+                                             in self.programs.values())}
         return art
 
     def save(self, path: str) -> dict:

@@ -401,13 +401,20 @@ class Router:
         """Route one arrival at admission instant ``t`` (the planes are
         first advanced to ``t`` so routing signals — load, live merge
         targets, cache residency — are current); returns the chosen
-        plane."""
+        plane.  A request bound for an engine plane opens its
+        ``request.queued`` span here; the engine moves it through its
+        phases."""
+        t_submit = self.tel.clock() if self.tel.enabled else None
         self.step(t)
         if self.plane_scaler is not None:
             self.plane_scaler.step(t, self._plane_signals(t))
         ctx = RoutingContext(_probe(item, t), t, shared=self.shared)
         plane, reason = self.policy.choose(self.planes, ctx)
-        plane.cp.schedule_arrival(t, plane.adapt(item, t))
+        payload = plane.adapt(item, t)
+        if payload is item and not isinstance(item, Task):
+            self.tel.begin("request.queued", ("req", id(item)),
+                           start=t_submit, plane=plane.pid)
+        plane.cp.schedule_arrival(t, payload)
         self.stats["submitted"] += 1
         self.stats["routed"][plane.pid] += 1
         if reason.startswith("affinity:"):
@@ -421,8 +428,9 @@ class Router:
 
     def step(self, until: float) -> None:
         """Advance every plane's event loop to (strictly before) ``until``."""
-        for p in self.planes:
-            p.cp.run(until=until)
+        with self.tel.span("router.step"):
+            for p in self.planes:
+                p.cp.run(until=until)
 
     def next_event_time(self) -> float | None:
         """Earliest scheduled event instant across the planes, or None —

@@ -46,7 +46,6 @@ from ..core.pmf import PMF
 from ..core.pruning import PruningConfig
 from ..core.tasks import Machine, Task
 from ..models import transformer as T
-from ..obs.profiling import profiled
 from .autoscale import ElasticityConfig, PoolScaler
 from .batching import (SeqState, StepBatchingConfig, UnitBatch, step_cost,
                        task_dims)
@@ -332,6 +331,15 @@ class _UnitRunner:
     trajectory fanned out, truncated per request).  Everything else
     (sampling, ``score``, over-long prompts) runs *exclusive*: the legacy
     ``ProcessingUnit.execute`` as one opaque step monopolizing the unit.
+
+    Each step runs under wall-clock spans on the engine's recorder
+    (``unit.step`` → per chunk ``unit.prefill`` with ``kv.upload``,
+    ``launch``, ``sync``, ``kv.download``, ``readback`` and
+    ``unit.commit``; per batch ``unit.decode`` with ``prepare``,
+    ``launch``, ``sync`` and ``readback``; ``launch`` and ``sync`` carry
+    the step program's name), and counts the K/V bytes it copies between
+    host and device (``kv_host_bytes``).  ``last_step`` holds the step's
+    wall time and K/V bytes for the ``batch_step`` event.
     """
 
     def __init__(self, engine: "ServingEngine", unit: ProcessingUnit,
@@ -352,6 +360,8 @@ class _UnitRunner:
         self._ticks = engine.cfg.time_scale / self.m.speed
         self.rp = 0.0   # wall seconds per prefill token
         self.rd = 0.0   # wall seconds per batch-1 decode step
+        self.last_step: dict | None = None
+        self._shapes: set = set()       # step-program shapes launched
         self.setup_wall = self._calibrate()
 
     def _calibrate(self) -> float:
@@ -364,13 +374,10 @@ class _UnitRunner:
         c = max(1, min(self.cfgb.step_token_budget, eng.cfg.max_len - 1))
         toks = jnp.zeros((1, c), jnp.int32)
         pk = jnp.zeros((mc.n_layers, 1, 0, hkv, hd), jnp.bfloat16)
-        jax.block_until_ready(
-            profiled("chunk_prefill", self._chunk, eng.params, toks, pk,
-                     pk)[0])
+        self._cold(("p", c, 0))
+        jax.block_until_ready(self._chunk(eng.params, toks, pk, pk)[0])
         t1 = time.perf_counter()
-        jax.block_until_ready(
-            profiled("chunk_prefill", self._chunk, eng.params, toks, pk,
-                     pk)[0])
+        jax.block_until_ready(self._chunk(eng.params, toks, pk, pk)[0])
         self.rp = max(time.perf_counter() - t1, 1e-9) / c
         for b in eng.cfg.batch_buckets:
             if b > self.cfgb.max_batch:
@@ -380,11 +387,10 @@ class _UnitRunner:
             tk = jnp.zeros((b,), jnp.int32)
             args = (eng.params, self.pages["kp"], self.pages["vp"],
                     tabs, lens, tk)
-            jax.block_until_ready(
-                profiled("paged_decode_step", self._pdec, *args)[0])
+            self._cold(("d", b))
+            jax.block_until_ready(self._pdec(*args)[0])
             t2 = time.perf_counter()
-            jax.block_until_ready(
-                profiled("paged_decode_step", self._pdec, *args)[0])
+            jax.block_until_ready(self._pdec(*args)[0])
             if b == 1:
                 self.rd = max(time.perf_counter() - t2, 1e-9)
         return time.perf_counter() - t0
@@ -423,6 +429,7 @@ class _UnitRunner:
             # path (measured wall, TPU batch discount for merged requests)
             dur = 0.0
             if reqs:
+                eng._req_phase(task, "request.prefill", machine=self.m.mid)
                 wall, _ = self.unit.execute(task, reqs, eng._rng,
                                             buckets=eng.cfg.batch_buckets)
                 dur = wall * self._ticks
@@ -449,11 +456,13 @@ class _UnitRunner:
         hit, p0, ks, vs = None, 0, [], []
         if cache is not None and plen > 1 \
                 and plen <= eng.cfg.prefix_max_prompt:
-            hit = cache.lookup(ptoks, max_tokens=plen - 1)
-            if hit:
-                pfx_k, pfx_v = eng._gather_prefix(hit)
-                p0 = pfx_k.shape[1]
-                ks, vs = [pfx_k], [pfx_v]
+            with eng.cp.tel.span("kv.lookup", plane=eng.cp.plane_id,
+                                 machine=self.m.mid):
+                hit = cache.lookup(ptoks, max_tokens=plen - 1)
+                if hit:
+                    pfx_k, pfx_v = eng._gather_prefix(hit)
+                    p0 = pfx_k.shape[1]
+                    ks, vs = [pfx_k], [pfx_v]
         eng.stats["prefill_tokens"] += plen - p0
         npg = -(-(plen + run_new) // self.ps)
         tab = np.zeros((self.mp,), np.int32)
@@ -464,7 +473,10 @@ class _UnitRunner:
             "prompt": prompt, "ptoks": ptoks, "tab": tab,
             "pids": pids, "hit": hit, "k": ks, "v": vs,
             "out": [], "cur": -1, "len": 0,
-            "pre": [first] if first is not None else []}
+            "pre": [first] if first is not None else [],
+            # the requests' queued spans, closed at the first chunk's
+            # dispatch (a continuation's requests are decoding already)
+            "queued": [] if first is not None else None}
         ub.join(seq, now)
 
     def release(self, seq: SeqState | None) -> None:
@@ -477,68 +489,118 @@ class _UnitRunner:
         self.free.extend(st["pids"])
 
     # -- step execution -------------------------------------------------------
+    def _moved(self, nbytes: int, direction: str) -> None:
+        """Count K/V bytes copied between host and device."""
+        self.eng.stats["kv_host_bytes"] += nbytes
+        self.eng.cp.tel.metrics.inc("kv_host_bytes", nbytes, dir=direction)
+
+    def _cold(self, shape) -> bool:
+        """Whether a step program's launch is the first of its shape."""
+        cold = shape not in self._shapes
+        self._shapes.add(shape)
+        return cold
+
     def exec_step(self, plan) -> float:
+        self.last_step = None
         if plan.exclusive is not None:
             return plan.exclusive.excl_left
         eng = self.eng
+        tel = eng.cp.tel
+        kv0 = eng.stats["kv_host_bytes"]
+        t0 = time.perf_counter()
+        with tel.span("unit.step", start=t0, plane=eng.cp.plane_id,
+                      machine=self.m.mid, decode=len(plan.decode),
+                      chunks=len(plan.chunks)) as sp:
+            vc = 0.0
+            for s, c in plan.chunks:
+                vc += self._prefill(s, c, tel)
+            vd = self._decode(plan.decode, tel) if plan.decode else 0.0
+            t1 = time.perf_counter()
+            sp.stop(t1)
+        self.last_step = {"wall_s": t1 - t0,
+                          "kv_bytes": eng.stats["kv_host_bytes"] - kv0}
+        dt = step_cost(vc, vd, self.cfgb.fused_step_overlap)
+        eng.stats["cost"] += dt * self.m.cost_rate
+        return dt
+
+    def _prefill(self, s: SeqState, c: int, tel) -> float:
+        """One prefill chunk of ``c`` tokens; returns its virtual cost."""
+        eng = self.eng
         mc = eng.model_cfg
-        vc = 0.0
-        for s, c in plan.chunks:
-            st = self.states[id(s)]
+        st = self.states[id(s)]
+        with tel.span("unit.prefill", tokens=c, prefix=s.prefill_done):
             t0 = time.perf_counter()
-            toks = jnp.asarray(
-                st["prompt"][None, s.prefill_done:s.prefill_done + c])
-            if st["k"]:
-                pk = jnp.asarray(np.concatenate(st["k"], axis=1))[:, None]
-                pv = jnp.asarray(np.concatenate(st["v"], axis=1))[:, None]
-            else:
-                pk = pv = jnp.zeros(
-                    (mc.n_layers, 1, 0, mc.n_kv_heads, mc.resolved_head_dim),
-                    jnp.bfloat16)
-            logits, kn, vn = profiled("chunk_prefill", self._chunk,
-                                      eng.params, toks, pk, pv)
-            jax.block_until_ready(logits)
-            st["k"].append(np.asarray(kn[:, 0]))
-            st["v"].append(np.asarray(vn[:, 0]))
+            if st["queued"] is None:
+                st["queued"] = eng._req_phase(s.task, "request.prefill", t0,
+                                              machine=self.m.mid)
+            with tel.span("kv.upload"):
+                toks = jnp.asarray(
+                    st["prompt"][None, s.prefill_done:s.prefill_done + c])
+                if st["k"]:
+                    pk = jnp.asarray(np.concatenate(st["k"], axis=1))[:, None]
+                    pv = jnp.asarray(np.concatenate(st["v"], axis=1))[:, None]
+                    self._moved(pk.nbytes + pv.nbytes, "h2d")
+                else:
+                    pk = pv = jnp.zeros(
+                        (mc.n_layers, 1, 0, mc.n_kv_heads,
+                         mc.resolved_head_dim), jnp.bfloat16)
+            cold = self._cold(("p", c, s.prefill_done))
+            with tel.span("launch", program="chunk_prefill", cold=cold):
+                logits, kn, vn = self._chunk(eng.params, toks, pk, pv)
+            with tel.span("sync", program="chunk_prefill"):
+                jax.block_until_ready(logits)
+            with tel.span("kv.download"):
+                st["k"].append(np.asarray(kn[:, 0]))
+                st["v"].append(np.asarray(vn[:, 0]))
+            self._moved(st["k"][-1].nbytes + st["v"][-1].nbytes, "d2h")
             self._obs_rate("rp", (time.perf_counter() - t0) / c)
             if s.prefill_done + c >= s.plen:
                 # final chunk: its last-position logits yield the first new
                 # token (what the sequential prefill's argmax produces) and
                 # the accumulated KV commits to this sequence's pages
-                st["cur"] = int(jnp.argmax(logits[0]))
+                with tel.span("readback"):
+                    st["cur"] = int(jnp.argmax(logits[0]))
                 st["out"].append(st["cur"])
-                self._commit(s, st)
-            vc += c * self.rp * self._ticks
-        vd = 0.0
-        k = len(plan.decode)
-        if k:
+                if not st["pre"]:
+                    eng._first_token(s.task, st["queued"], self.m)
+                with tel.span("unit.commit"):
+                    self._commit(s, st)
+        return c * self.rp * self._ticks
+
+    def _decode(self, seqs: list, tel) -> float:
+        """One batched decode launch; returns its virtual cost."""
+        eng = self.eng
+        k = len(seqs)
+        with tel.span("unit.decode", batch=k):
             t0 = time.perf_counter()
-            bucket = next((b for b in eng.cfg.batch_buckets if b >= k), k)
-            toks = np.zeros((bucket,), np.int32)
-            tabs = np.zeros((bucket, self.mp), np.int32)
-            lens = np.zeros((bucket,), np.int32)
-            sts = [self.states[id(s)] for s in plan.decode]
-            for i, st in enumerate(sts):
-                toks[i] = st["cur"]
-                tabs[i] = st["tab"]
-                lens[i] = st["len"]
-            logits, kp, vp = profiled(
-                "paged_decode_step", self._pdec,
-                eng.params, self.pages["kp"], self.pages["vp"],
-                jnp.asarray(tabs), jnp.asarray(lens), jnp.asarray(toks))
-            jax.block_until_ready(logits)
+            with tel.span("prepare"):
+                bucket = next((b for b in eng.cfg.batch_buckets if b >= k), k)
+                toks = np.zeros((bucket,), np.int32)
+                tabs = np.zeros((bucket, self.mp), np.int32)
+                lens = np.zeros((bucket,), np.int32)
+                sts = [self.states[id(s)] for s in seqs]
+                for i, st in enumerate(sts):
+                    toks[i] = st["cur"]
+                    tabs[i] = st["tab"]
+                    lens[i] = st["len"]
+                args = (jnp.asarray(tabs), jnp.asarray(lens),
+                        jnp.asarray(toks))
+            with tel.span("launch", program="paged_decode_step",
+                          cold=self._cold(("d", bucket))):
+                logits, kp, vp = self._pdec(
+                    eng.params, self.pages["kp"], self.pages["vp"], *args)
+            with tel.span("sync", program="paged_decode_step"):
+                jax.block_until_ready(logits)
             self.pages = {"kp": kp, "vp": vp}
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with tel.span("readback"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
             for i, st in enumerate(sts):
                 st["len"] += 1
                 st["cur"] = int(nxt[i])
                 st["out"].append(st["cur"])
             self._obs_rate("rd", (time.perf_counter() - t0) / k)
-            vd = (1.0 + self.cfgb.batch_marginal_cost * (k - 1)) \
-                * self.rd * self._ticks
-        dt = step_cost(vc, vd, self.cfgb.fused_step_overlap)
-        eng.stats["cost"] += dt * self.m.cost_rate
-        return dt
+        return (1.0 + self.cfgb.batch_marginal_cost * (k - 1)) \
+            * self.rd * self._ticks
 
     def _commit(self, s: SeqState, st: dict) -> None:
         """Scatter the sequence's accumulated prefill KV into its pages."""
@@ -547,10 +609,13 @@ class _UnitRunner:
         st["k"], st["v"] = [kk], [vv]
         npg = -(-s.plen // self.ps)
         pids = jnp.asarray(st["pids"][:npg], jnp.int32)
-        self.pages = {
-            name: self.pages[name].at[:, pids].set(jnp.asarray(
-                T.kv_to_pages(x, self.ps), self.pages[name].dtype))
-            for name, x in (("kp", kk), ("vp", vv))}
+        pages = {}
+        for name, x in (("kp", kk), ("vp", vv)):
+            up = jnp.asarray(T.kv_to_pages(x, self.ps),
+                             self.pages[name].dtype)
+            self._moved(up.nbytes, "h2d")
+            pages[name] = self.pages[name].at[:, pids].set(up)
+        self.pages = pages
         st["len"] = s.plen
 
     # -- completion -----------------------------------------------------------
@@ -568,8 +633,10 @@ class _UnitRunner:
         if cache is not None and s.plen > 1 \
                 and s.plen <= eng.cfg.prefix_max_prompt:
             kk, vv = st["k"][0], st["v"][0]
-            cache.insert(st["ptoks"],
-                         lambda s0, s1: (kk[:, s0:s1], vv[:, s0:s1]))
+            with eng.cp.tel.span("unit.complete", plane=eng.cp.plane_id,
+                                 machine=self.m.mid):
+                cache.insert(st["ptoks"],
+                             lambda s0, s1: (kk[:, s0:s1], vv[:, s0:s1]))
             if st["hit"]:
                 cache.release(st["hit"])
         self.free.extend(st["pids"])
@@ -696,7 +763,10 @@ class ServingEngine(Substrate):
                       "pruning_wall_s": 0.0,
                       "prefix_hits": 0, "prefix_candidates": 0,
                       "prefix_tokens_reused": 0,
-                      "prefill_tokens": 0}  # prefix_* mirrored from kvcache
+                      "prefill_tokens": 0,  # prefix_* mirrored from kvcache
+                      # live step loop: K/V bytes copied host<->device
+                      # (``_UnitRunner``)
+                      "kv_host_bytes": 0}
         self._tel = None                    # obs.Telemetry once attached
         self.cp = ControlPlane(self, cfg.control())
         #: per-unit paged KV caches, mid -> PrefixKVCache (DESIGN.md §2.4 /
@@ -892,6 +962,57 @@ class ServingEngine(Substrate):
         req.completed_at = now
         self.stats["dropped"] += 1
 
+    # -- per-request wall-clock phases (keyed spans, DESIGN.md §2.9) -----------
+    # ``Router.submit`` opens ``request.queued``; the dispatch of a request's
+    # first prefill chunk moves it to ``request.prefill``, its first token to
+    # ``request.decode``, and completion or a drop closes it.  Paths that
+    # cannot see the first token (stub units, run-to-completion, exclusive
+    # steps) stay in ``request.prefill`` until they finish.
+    def _req_phase(self, task: Task, name: str, at: float | None = None,
+                   **attrs) -> list:
+        """Move ``task``'s requests into phase ``name``; returns the
+        records of the phases they left (None where none was open)."""
+        tel = self.cp.tel
+        if not tel.enabled:
+            return []
+        ids = {"task": self.cp._index(task)}
+        left = []
+        for r in self._inflight.get(task.tid, []):
+            ids["rid"] = r.rid
+            rec = tel.begin(name, ("req", id(r)), start=at, **ids, **attrs)
+            if rec is not None:
+                # the front door opened ``request.queued`` before the
+                # request had an id
+                for k, v in ids.items():
+                    rec.setdefault(k, v)
+            left.append(rec)
+        return left
+
+    def _req_end(self, reqs, outcome: str) -> None:
+        tel = self.cp.tel
+        if tel.enabled:
+            for r in reqs:
+                rec = tel.end(("req", id(r)), outcome=outcome)
+                if rec is not None:
+                    rec.setdefault("rid", r.rid)
+
+    def _first_token(self, task: Task, queued: list, m: Machine) -> None:
+        """The first-token stamp: ``request.prefill`` → ``request.decode``
+        and one ``first_token`` event per request, carrying the wall
+        seconds the request spent queued (its ``request.queued`` span)."""
+        tel = self.cp.tel
+        if not tel.enabled:
+            return
+        t_first = tel.clock()
+        self._req_phase(task, "request.decode", t_first, machine=m.mid)
+        t = self._batches[m.mid].clock
+        for i, r in enumerate(self._inflight.get(task.tid, [])):
+            q = queued[i] if i < len(queued) else None
+            tel.event(t, "first_token", wall=t_first, rid=r.rid,
+                      task=self.cp._index(task), plane=self.cp.plane_id,
+                      machine=m.mid,
+                      queued_s=None if q is None else q["end"] - q["start"])
+
     # -- ingestion (Ch. 4 front door) ----------------------------------------
     def ingest(self, req: Request, now: float) -> Task | None:
         req.rid = self._rid
@@ -900,6 +1021,7 @@ class ServingEngine(Substrate):
         if self.cfg.result_cache and req.op == "generate" and sig in self.cache:
             req.tokens = list(self.cache[sig])
             self.stats["cache_hits"] += 1
+            self._req_end([req], "served")
             # same accounting path as a real execution: a hit served past
             # its deadline counts as missed (simulator semantics)
             self._account_completed(req, now)
@@ -958,11 +1080,21 @@ class ServingEngine(Substrate):
         if ub is None:
             def on_step(t, dt, plan):
                 tel = self.cp.tel
-                if tel.enabled:
-                    tel.event(t, "batch_step", machine=m.mid,
-                              plane=self.cp.plane_id, dt=round(dt, 9),
-                              tokens=plan.tokens, decode=len(plan.decode),
-                              chunks=len(plan.chunks))
+                if not tel.enabled:
+                    return
+                # a live step also reports its wall time and the K/V bytes
+                # it moved; a stub unit's step is modelled only
+                runner = self._runners.get(m.mid)
+                live = runner.last_step if runner is not None else None
+                tel.event(t, "batch_step", machine=m.mid,
+                          plane=self.cp.plane_id, dt=round(dt, 9),
+                          tokens=plan.tokens, decode=len(plan.decode),
+                          chunks=len(plan.chunks),
+                          step_s=live and live["wall_s"],
+                          kv_bytes=live and live["kv_bytes"])
+                if live:
+                    tel.metrics.observe("step_ms", live["wall_s"] * 1e3)
+                else:
                     tel.metrics.observe("step_ticks", dt)
 
             ub = self._batches[m.mid] = UnitBatch(self.cfg.batching,
@@ -989,6 +1121,8 @@ class ServingEngine(Substrate):
         ub = self._unit_batch(m)
         unit = self._unit(m.mid)
         if self._stub or unit.kind == "stub":
+            if task.tid not in self._handoff_cont:
+                self._req_phase(task, "request.prefill", machine=m.mid)
             task._stub_backend = not self._stub
             cfgb = self.cfg.batching
             cont = self._handoff_cont.pop(task.tid, None)
@@ -1099,6 +1233,7 @@ class ServingEngine(Substrate):
         self._inflight[task.tid] = reqs
         if not reqs:
             return 0.0
+        self._req_phase(task, "request.prefill", machine=m.mid)
         unit = self._unit(m.mid)
         if self._stub or unit.kind == "stub":
             # per-unit backend dispatch: a stub-backed unit in a live pool
@@ -1156,6 +1291,7 @@ class ServingEngine(Substrate):
         # empty results must not poison the result cache
         cacheable = (self.cfg.result_cache
                      and not getattr(task, "_stub_backend", False))
+        self._req_end(reqs, "done")
         missed = 0
         for r in reqs:
             missed += self._account_completed(r, now, ttype=task.ttype)
@@ -1173,6 +1309,7 @@ class ServingEngine(Substrate):
         # dropped is its own bucket (simulator semantics): "missed" counts
         # only tasks that *ran* late, so miss-rate consumers combine
         # missed + dropped — exactly like SimStats.miss_rate
+        self._req_end(reqs, "dropped")
         for r in reqs:
             self._account_dropped(r, now)
 

@@ -2,12 +2,14 @@
 vs numpy, metrics registry + exporter formats, zero-perturbation of the
 telemetry recorder on both substrates, sim<->engine event-stream
 diffability, decision attribution (drop/defer reason + chance-of-success
-at decision time), kernel-profiler seam, and the unified engine
-completion/drop accounting (one path for cache hits, executions and
-drops).  No JAX anywhere in this file — stub-execution engines only."""
+at decision time), wall-clock spans and kernel launches on them, and the
+unified engine completion/drop accounting (one path for cache hits,
+executions and drops).  No JAX anywhere in this file — stub-execution
+engines only."""
 
 import json
 import math
+from collections import deque
 import subprocess
 import sys
 
@@ -479,41 +481,225 @@ class TestKVCacheTelemetry:
 
 
 # ---------------------------------------------------------------------------
-# kernel profiler seam (no JAX: profiled() wraps plain callables too)
+# wall-clock spans (Telemetry.span / begin / end)
 # ---------------------------------------------------------------------------
 
-class TestKernelProfiler:
+class _Clock:
+    """A hand-driven clock: every read advances it by one."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+class TestSpans:
+    def test_nesting_and_parent_ids(self):
+        tel = Telemetry()
+        tel.clock = _Clock()
+        with tel.span("outer", unit=3):
+            with tel.span("a"):
+                pass
+            with tel.span("b"):
+                with tel.span("c"):
+                    pass
+        by = {s["name"]: s for s in tel.spans}
+        assert by["outer"]["parent"] is None and by["outer"]["unit"] == 3
+        assert by["a"]["parent"] == by["b"]["parent"] == by["outer"]["id"]
+        assert by["c"]["parent"] == by["b"]["id"]
+        # children close first, and every span lies inside its parent
+        assert [s["name"] for s in tel.spans] == ["a", "c", "b", "outer"]
+        for s in tel.spans:
+            if s["parent"] is not None:
+                up = next(p for p in tel.spans if p["id"] == s["parent"])
+                assert up["start"] < s["start"] < s["end"] < up["end"]
+        assert tel.events == []          # spans never reach the event log
+
+    def test_explicit_start_and_stop_share_the_callers_reads(self):
+        tel = Telemetry()
+        with tel.span("cp.map", start=10.0) as sp:
+            sp.stop(12.5)
+        (rec,) = tel.spans
+        assert (rec["start"], rec["end"]) == (10.0, 12.5)
+        assert sp.duration == 2.5
+
+    def test_keyed_spans_change_phase_at_one_instant(self):
+        tel = Telemetry()
+        tel.clock = _Clock()
+        with tel.span("router.step"):
+            tel.begin("request.queued", "r1", plane=0)
+        left = tel.begin("request.prefill", "r1", start=5.0)
+        assert left["name"] == "request.queued" and left["end"] == 5.0
+        assert left["parent"] is None and left["keyed"]
+        done = tel.end("r1", end=9.0, outcome="done")
+        assert (done["start"], done["end"]) == (5.0, 9.0)
+        assert done["outcome"] == "done"
+        assert tel.end("r1") is None and tel.end("never") is None
+        assert [s["name"] for s in tel.spans_of(
+            "request.queued", "request.prefill")] == \
+            ["request.queued", "request.prefill"]
+
+    def test_ring_counts_dropped_spans(self):
+        tel = Telemetry()
+        tel.spans = deque(maxlen=4)
+        for i in range(10):
+            with tel.span(f"s{i}"):
+                pass
+        assert [s["name"] for s in tel.spans] == ["s6", "s7", "s8", "s9"]
+        assert tel.spans_dropped == 6
+
+    def test_annotate_hook_opens_each_nested_span(self):
+        seen = []
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        tel = Telemetry(annotate=Ann)
+        with tel.span("unit.step"):
+            with tel.span("launch"):
+                pass
+        tel.begin("request.queued", 1)       # keyed: memory only
+        tel.end(1)
+        assert seen == [("enter", "unit.step"), ("enter", "launch"),
+                        ("exit", "launch"), ("exit", "unit.step")]
+
+    def test_null_telemetry_records_no_spans(self):
+        null = NullTelemetry()
+        with null.span("a", start=1.0) as sp:
+            sp.stop(2.0)
+            with null.span("b"):
+                pass
+        null.begin("request.queued", 1)
+        assert null.end(1) is None
+        assert null.spans == () and null.spans_of("a") == []
+        assert null.span("x") is null.span("y")     # one shared no-op
+
+    def test_mapping_counters_equal_the_control_plane_spans(self):
+        """``mapping_wall_s`` / ``pruning_wall_s`` and the ``cp.map`` /
+        ``cp.prune`` spans come from the same clock reads."""
+        tel = Telemetry()
+        eng, stats = _stub_engine(
+            _request_trace(n=40, deadline=20.0, rate=2.0), tel, PRUNED_CFG)
+        maps = tel.spans_of("cp.map")
+        prunes = tel.spans_of("cp.prune")
+        assert len(maps) == stats["mapping_events"]
+        assert sum(s["end"] - s["start"] for s in maps) == \
+            pytest.approx(stats["mapping_wall_s"], rel=1e-9, abs=1e-12)
+        assert sum(s["end"] - s["start"] for s in prunes) == \
+            pytest.approx(stats["pruning_wall_s"], rel=1e-9, abs=1e-12)
+        ids = {s["id"] for s in maps}
+        assert prunes and all(p["parent"] in ids for p in prunes)
+
+    def test_router_request_phases_close_on_every_path(self):
+        """Behind a Router each request opens ``request.queued`` at submit;
+        the stub engine moves it to ``request.prefill`` at dispatch and
+        closes it on completion, a drop or a result-cache hit."""
+        from repro.serving.cluster import Router
+
+        tel = Telemetry()
+        eng = ServingEngine(None, None, EngineConfig(
+            n_units=1, elasticity=None, result_cache=True,
+            prefix_cache=False, **PRUNED_CFG),
+            stub_oracle=PETOracle(_pet(), seed=11))
+        router = Router([eng], telemetry=tel)
+        trace = _request_trace(n=40, deadline=20.0, rate=2.0)
+        stats = router.run(trace)
+        assert tel._open == {}
+        queued = tel.spans_of("request.queued")
+        assert len(queued) == len(trace)
+        outcomes = [s.get("outcome") for s in tel.spans
+                    if s["name"].startswith("request.")]
+        assert outcomes.count("done") == stats["completed"] - \
+            stats["cache_hits"]
+        assert outcomes.count("dropped") == stats["dropped"]
+        assert outcomes.count("served") == stats["cache_hits"]
+
+    def test_decisions_identical_with_spans_annotated_or_off(self):
+        from contextlib import nullcontext
+        trace = _request_trace(n=40, deadline=20.0, rate=2.0)
+        on, _ = _stub_engine(trace, Telemetry(
+            annotate=lambda name: nullcontext()), PRUNED_CFG)
+        off, _ = _stub_engine(trace, None, PRUNED_CFG)
+        assert on.cp.trace == off.cp.trace
+
+
+# ---------------------------------------------------------------------------
+# kernel launches on the span API (no JAX: profiled() wraps plain callables)
+# ---------------------------------------------------------------------------
+
+class TestProfiledSpans:
     def teardown_method(self):
         profiling.install(None)
 
-    def test_passthrough_without_profiler(self):
+    def test_passthrough_without_recorder(self):
         assert profiling.current() is None
         assert profiling.profiled("f", lambda x: x + 1, 2) == 3
 
-    def test_launch_records_and_flags_cold(self):
-        m = MetricsRegistry()
-        prof = profiling.KernelProfiler(metrics=m)
-        profiling.install(prof)
+    def test_launch_span_with_dispatch_and_sync_children(self):
+        tel = Telemetry()
+        profiling.install(tel)
         a = np.zeros((4, 8), np.float32)
         assert profiling.profiled("conv", np.sum, a) == 0.0
         profiling.profiled("conv", np.sum, a)           # same shape: warm
         profiling.profiled("conv", np.sum, np.zeros((2, 2)))  # new shape
-        assert [r["cold"] for r in prof.records] == [True, False, True]
-        assert all(r["dispatch_s"] >= 0 and r["execute_s"] >= 0
-                   for r in prof.records)
-        s = prof.summary()
-        assert s["conv"]["launches"] == 3
-        assert s["conv"]["cold_launches"] == 2
+        launches = tel.spans_of("kernel.conv")
+        assert [s["cold"] for s in launches] == [True, False, True]
+        ids = {s["id"] for s in launches}
+        kids = [s for s in tel.spans if s["parent"] in ids]
+        assert [s["name"] for s in kids] == ["dispatch", "sync"] * 3
+        assert all(s["end"] >= s["start"] for s in kids)
+        m = tel.metrics
         assert m.counter_value("kernel_launches", kernel="conv") == 3
         assert m.histogram("kernel_dispatch_s", kernel="conv",
                            cold="true").count == 2
 
-    def test_shape_key_separates_dtypes(self):
-        prof = profiling.KernelProfiler()
-        profiling.install(prof)
+    def test_cold_per_dtype(self):
+        tel = Telemetry()
+        profiling.install(tel)
         profiling.profiled("k", np.sum, np.zeros(4, np.float32))
         profiling.profiled("k", np.sum, np.zeros(4, np.int32))
-        assert [r["cold"] for r in prof.records] == [True, True]
+        profiling.profiled("k", np.sum, np.zeros(4, np.int32))
+        assert [s["cold"] for s in tel.spans_of("kernel.k")] == \
+            [True, True, False]
+
+    def test_reinstall_forgets_shapes(self):
+        tel = Telemetry()
+        profiling.install(tel)
+        profiling.profiled("k", np.sum, np.zeros(4))
+        profiling.install(tel)
+        profiling.profiled("k", np.sum, np.zeros(4))
+        assert [s["cold"] for s in tel.spans_of("kernel.k")] == [True, True]
+
+    def test_flight_recorder_summarises_kernel_spans(self):
+        from repro.obs import FlightRecorder
+        rec = FlightRecorder(capacity=16)
+        profiling.install(rec)
+        profiling.profiled("conv", np.sum, np.zeros(3))
+        profiling.profiled("conv", np.sum, np.zeros(3))
+        # a step program's launch/sync pair inside the unit's step loop
+        with rec.span("unit.decode"):
+            with rec.span("launch", program="paged_decode_step", cold=True):
+                pass
+            with rec.span("sync", program="paged_decode_step"):
+                pass
+        # the sums cover launches the span ring has already dropped
+        rec.spans.clear()
+        art = rec.to_artifact()
+        conv = art["kernel"]["summary"]["conv"]
+        assert (conv["launches"], conv["cold_launches"]) == (2, 1)
+        dec = art["kernel"]["summary"]["paged_decode_step"]
+        assert (dec["launches"], dec["cold_launches"]) == (1, 1)
+        assert art["kernel"]["launches"] == 3
+        assert conv["dispatch_s"] >= 0 and conv["execute_s"] >= 0
 
 
 # ---------------------------------------------------------------------------
