@@ -1,16 +1,24 @@
-"""Pallas TPU kernel: flash-decode GQA attention.
+"""Pallas TPU kernels: flash-decode GQA attention, contiguous and paged.
 
 One new token attends over a long KV cache — the serving engine's hot loop
 (decode_32k / long_500k shapes).  The XLA fallback materializes the (B, H,
-S) score tensor in HBM; this kernel streams KV blocks through VMEM with an
-online softmax, so HBM traffic is exactly one read of K/V plus O(B*H*hd).
+S) score tensor in HBM; these kernels stream KV blocks through VMEM with an
+online softmax, so HBM traffic is one read of the live K/V plus O(B*H*hd).
 
-Grid: (B, Hkv, S / BS) — batch x kv-head x kv-block.  For each (b, g):
+Contiguous (``decode_attention_pallas``), grid (B, Hkv, S / BS) — batch x
+kv-head x kv-block.  For each (b, g):
   q tile    (G, hd)      G = query heads per kv head (GQA group)
   k/v block (BS, hd)
   carry     m (G,), l (G,), acc (G, hd)  — kept in the output refs between
             sequential grid steps over the kv-block axis (TPU grid is
             executed sequentially per (b, g), making the carry legal).
+
+Paged (``paged_decode_attention_pallas``), grid (B, MP / ppb) — sequence x
+block of ``ppb`` consecutive page-table slots, every kv head at once.  A
+step reads its block's pages, whole (Hkv, PS, hd) slices of the pool,
+through the block table, and attends q (Hkv, G, hd) to them in one batched
+product.  Blocks past a sequence's length are neither copied nor computed,
+so the walk follows the live context, not the table's capacity.
 """
 
 from __future__ import annotations
@@ -132,11 +140,33 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths,
     return out.reshape(b, h, hd).astype(q.dtype)
 
 
-def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_ref, l_ref, *, page_size: int, hd: int):
+# tokens a paged grid step may span; the pages per step follow from shapes
+BLOCK_TOKENS = 256
+
+
+def pages_per_block(mp: int, ps: int) -> int:
+    """Table pages the paged kernel reads per grid step.
+
+    The largest power of two that divides ``mp`` (pages per table) and
+    spans at most ``BLOCK_TOKENS`` tokens of ``ps``-token pages, so a
+    table is a whole number of blocks: 16 at 16-token pages and 128-page
+    tables, 1 at 3-page tables.
+    """
+    ppb = 1
+    while mp % (2 * ppb) == 0 and 2 * ppb * ps <= BLOCK_TOKENS:
+        ppb *= 2
+    return ppb
+
+
+def _paged_kernel(tables_ref, len_ref, q_ref, *refs, ppb: int,
+                  page_size: int, hd: int):
     del tables_ref  # consumed by the BlockSpec index maps (scalar prefetch)
-    j = pl.program_id(2)
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_ref, l_ref = refs[2 * ppb:]
+    j = pl.program_id(1)
+    bk = ppb * page_size
     length = len_ref[pl.program_id(0)]
+    last = jnp.logical_or((j + 1) * bk >= length, j == pl.num_programs(1) - 1)
 
     @pl.when(j == 0)
     def _init():
@@ -144,30 +174,30 @@ def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    q = q_ref[0, 0]                     # (G, hd)
-    k = k_ref[0, 0]                     # (PS, hd)
-    v = v_ref[0, 0]
-    scale = 1.0 / math.sqrt(hd)
+    @pl.when(jnp.logical_or(j == 0, j * bk < length))
+    def _block():
+        q = q_ref[0].astype(jnp.float32)                   # (Hkv, G, hd)
+        # the block's pages, (Hkv, PS, hd) each -> (Hkv, ppb * PS, hd)
+        k = jnp.concatenate([r[0] for r in k_refs], axis=1)
+        v = jnp.concatenate([r[0] for r in v_refs], axis=1)
+        s = jnp.einsum("hgd,htd->hgt", q, k.astype(jnp.float32),
+                       preferred_element_type=jnp.float32) / math.sqrt(hd)
+        pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(pos < length, s, -1e30)
+        m_prev = m_ref[...]                                # (Hkv, G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(pos < length, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        o_ref[0] = o_ref[0] * alpha + jnp.einsum(
+            "hgt,htd->hgd", p, v.astype(jnp.float32),
+            preferred_element_type=jnp.float32)
 
-    s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T) * scale
-    pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < length, s, -1e30)
-
-    m_prev = m_ref[0, 0]                # (G, 1)
-    l_prev = l_ref[0, 0]
-    m_new = jnp.maximum(m_prev[:, 0], s.max(axis=-1))[:, None]
-    p = jnp.exp(s - m_new)
-    p = jnp.where(pos < length, p, 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-    acc = o_ref[0, 0] * alpha + jnp.dot(p, v.astype(jnp.float32))
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_new
-    o_ref[0, 0] = acc
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        o_ref[0, 0] = o_ref[0, 0] / jnp.maximum(l_ref[0, 0], 1e-20)
+        @pl.when(last)
+        def _done():
+            o_ref[0] = o_ref[0] / jnp.maximum(l_ref[...], 1e-20)
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
@@ -177,48 +207,68 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
     q (B, H, hd); k/v_pages (NP, Hkv, PS, hd); block_tables (B, MP) int32
     page indices per sequence; lengths (B,) -> (B, H, hd).
 
-    Same online-softmax carry as the contiguous kernel, but the kv block
-    for grid step (b, g, j) is gathered through the block-table ref: the
-    BlockSpec index map reads ``tables[b, j]`` via scalar prefetch
-    (``pltpu.PrefetchScalarGridSpec``), so each sequence streams its own
-    scattered pages through VMEM.  The kv-head axis sits before the page
-    slot so that each block is one (PS, hd) tile of one head — the last two
-    block dimensions then meet the TPU's (8, 128) tiling rule.  Ragged
-    ``lengths`` (the second scalar-prefetch operand) are handled by the
-    positional mask — table entries past a sequence's last page may point
-    anywhere (conventionally page 0) and contribute nothing.
+    Grid ``(B, MP // ppb)``: one step per sequence and block of ``ppb``
+    consecutive table slots (:func:`pages_per_block`), all KV heads at
+    once.  The kernel takes the pool ``ppb`` times each for K and V, one
+    operand per slot of the block, whose BlockSpec index map reads the
+    slot's page from the block table (``tables[b, j * ppb + p]``, scalar
+    prefetch) and copies that whole page, a contiguous (Hkv, PS, hd) slice
+    of the pool, into VMEM; the pipeline double-buffers every copy and
+    starts the next step's copies before the current step computes.  The
+    pages stay where they are: neither the pool nor the tables are
+    gathered or relaid out.
+
+    Blocks that start at or past a sequence's length (the second scalar
+    prefetch operand) are skipped: their index maps repeat the row's last
+    live block, so the pipeline copies nothing, and ``pl.when`` skips the
+    compute; only the grid step itself remains.  Block 0 is always live,
+    so a padding row of length 1 costs one block.
+
+    Per live step the scores are a batched product over heads, q (Hkv, G,
+    hd) against the block's (Hkv, ppb * PS, hd) keys, in f32 from bf16
+    pages; the online-softmax carry m, l (Hkv, G, 1) lives in VMEM scratch
+    and the accumulator in the output block, normalized on the row's last
+    live block.  Positions past ``length`` inside that block are masked,
+    so table entries past a sequence's last page may point at any page of
+    the pool (conventionally page 0) and contribute nothing; they must be
+    valid page indices, since a live block copies all of its pages.
+
+    The pages are copied by the pipeline rather than by manual DMAs from
+    an HBM ref because Mosaic refuses a manual copy of a page whose last
+    dimension is narrower than a 128-lane tile (SmolLM's head_dim 64).
     """
     np_, hkv, ps, hd = k_pages.shape
     b, h = q.shape[0], q.shape[1]
     g = h // hkv
     mp = block_tables.shape[1]
+    ppb = pages_per_block(mp, ps)
+    bk = ppb * ps
     qg = q.reshape(b, hkv, g, hd)
-    kernel = functools.partial(_paged_kernel, page_size=ps, hd=hd)
+    kernel = functools.partial(_paged_kernel, ppb=ppb, page_size=ps, hd=hd)
+
+    def page_spec(p):
+        def index(i, j, t, n):
+            # a dead block maps to the row's last live one: same block
+            # index as the step before, so the pipeline copies nothing
+            jj = jnp.minimum(j, jnp.maximum((n[i] + bk - 1) // bk - 1, 0))
+            return t[i, jj * ppb + p], 0, 0, 0
+        return pl.BlockSpec((1, hkv, ps, hd), index)
+
+    row = pl.BlockSpec((1, hkv, g, hd), lambda i, j, t, n: (i, 0, 0, 0))
+    pages = [page_spec(p) for p in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, t, n: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd),
-                         lambda i, j, k, t, n: (t[i, k], j, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd),
-                         lambda i, j, k, t, n: (t[i, k], j, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, k, t, n: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, t, n: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, k, t, n: (i, j, 0, 0)),
-        ],
+        grid=(b, mp // ppb),
+        in_specs=[row] + pages + pages,
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, 1), jnp.float32)],
     )
-    out, m, l = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), jnp.float32),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg,
-      k_pages, v_pages)
+      *[k_pages] * ppb, *[v_pages] * ppb)
     return out.reshape(b, h, hd).astype(q.dtype)

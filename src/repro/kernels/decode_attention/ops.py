@@ -7,6 +7,13 @@ so real backends never pay the interpreter tax.  ``use_kernel=False``
 falls back to the jnp oracle; the paged front door defaults
 ``use_kernel=None`` → oracle off-accelerator (XLA-compiled gather +
 softmax is the fast exact path there) and kernel on TPU/GPU.
+
+The paged kernel walks one grid step per sequence and block of
+``pages_per_block(MP, PS)`` table slots, all KV heads at once, copying
+only the live blocks' pages into VMEM and skipping the compute of the
+rest (``paged_decode_attention_pallas``).  Its one ``pallas_call`` sits
+inside ``_paged_decode_attention_jit``, so on the device the kernel is the
+``%_paged_decode_attention_jit…`` custom call the trace reduction finds.
 """
 
 from __future__ import annotations
@@ -16,13 +23,13 @@ from functools import partial
 import jax
 
 from .. import interpret_default
-from .decode_attention import (decode_attention_pallas,
+from .decode_attention import (decode_attention_pallas, pages_per_block,
                                paged_decode_attention_pallas, tune_block_s)
 from .ref import decode_attention_ref, paged_decode_attention_ref
 from ...obs.profiling import profiled
 
-__all__ = ["decode_attention", "paged_decode_attention", "tune_block_s",
-           "interpret_default"]
+__all__ = ["decode_attention", "paged_decode_attention", "pages_per_block",
+           "tune_block_s", "interpret_default"]
 
 
 @partial(jax.jit, static_argnames=("block_s", "interpret", "use_kernel"))

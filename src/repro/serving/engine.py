@@ -45,6 +45,7 @@ from ..core.fleet import DEFAULT_MTYPE, FleetSpec, MachineSpec
 from ..core.pmf import PMF
 from ..core.pruning import PruningConfig
 from ..core.tasks import Machine, Task
+from ..kernels.decode_attention.ops import pages_per_block
 from ..models import transformer as T
 from .autoscale import ElasticityConfig, PoolScaler
 from .batching import (SeqState, StepBatchingConfig, UnitBatch, step_cost,
@@ -338,7 +339,9 @@ class _UnitRunner:
     ``unit.commit``; per batch ``unit.decode`` with ``prepare``,
     ``launch``, ``sync`` and ``readback``; ``launch`` and ``sync`` carry
     the step program's name), and counts the K/V bytes it copies between
-    host and device (``kv_host_bytes``).  ``last_step`` holds the step's
+    host and device (``kv_host_bytes``) and, per decode launch, the blocks
+    of pages the paged kernel reads and walks (``paged_kv_blocks``,
+    ``kind=live|grid``).  ``last_step`` holds the step's
     wall time and K/V bytes for the ``batch_step`` event.
     """
 
@@ -351,6 +354,7 @@ class _UnitRunner:
         mc = engine.model_cfg
         self.ps = engine.cfg.kv_block_size
         self.mp = -(-engine.cfg.max_len // self.ps)     # pages per sequence
+        self.ppb = pages_per_block(self.mp, self.ps)    # the kernel's block
         n_pages = cfgb.max_batch * self.mp + 1          # page 0: pad scratch
         self.pages = T.init_paged_cache(mc, n_pages, self.ps)
         self.free = list(range(1, n_pages))
@@ -583,6 +587,13 @@ class _UnitRunner:
                     toks[i] = st["cur"]
                     tabs[i] = st["tab"]
                     lens[i] = st["len"]
+                # the step attends over lens + 1 tokens: blocks it reads,
+                # of those its grid walks
+                bk = self.ppb * self.ps
+                tel.metrics.inc("paged_kv_blocks",
+                                int(np.sum(-(-(lens + 1) // bk))), kind="live")
+                tel.metrics.inc("paged_kv_blocks",
+                                bucket * (self.mp // self.ppb), kind="grid")
                 args = (jnp.asarray(tabs), jnp.asarray(lens),
                         jnp.asarray(toks))
             with tel.span("launch", program="paged_decode_step",

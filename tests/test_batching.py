@@ -143,58 +143,89 @@ class TestPagedDecodeKernel:
         tables = jnp.asarray(perm[:b * mp].reshape(b, mp), jnp.int32)
         return q, kp, vp, tables
 
-    def test_kernel_matches_ref_ragged_lengths(self):
+    # (b, mp, ps, h, hkv, hd, lengths); pages per block in the comment
+    CASES = {
+        "mp3_ragged": (4, 3, 8, 4, 2, 16, [1, 7, 13, 24]),        # ppb 1
+        "mp_lt_ppb": (3, 4, 16, 4, 2, 16, [1, 16, 64]),            # ppb 4
+        "block_edges": (4, 32, 16, 4, 2, 16, [1, 16, 256, 257]),   # ppb 16
+        "g8_hd128": (2, 32, 16, 32, 4, 128, [257, 512]),           # ppb 16
+        "hkv1": (3, 32, 16, 4, 1, 16, [1, 255, 300]),              # ppb 16
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_kernel_matches_ref_ragged_lengths(self, case):
         import jax.numpy as jnp
         from repro.kernels.decode_attention.ops import paged_decode_attention
         from repro.kernels.decode_attention.ref import \
             paged_decode_attention_ref
-        b, mp, ps = 4, 3, 8
-        q, kp, vp, tables = self._data(b, mp, ps, 4, 2, 16)
-        lengths = jnp.asarray([1, 7, 13, 24], jnp.int32)   # ragged, max full
+        b, mp, ps, h, hkv, hd, lens = self.CASES[case]
+        q, kp, vp, tables = self._data(b, mp, ps, h, hkv, hd)
+        lengths = jnp.asarray(lens, jnp.int32)
         out = paged_decode_attention(q, kp, vp, tables, lengths,
                                      interpret=True, use_kernel=True)
         ref = paged_decode_attention_ref(q, kp, vp, tables, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5, rtol=3e-5)
 
-    def test_single_masked_block_edge(self):
+    @pytest.mark.parametrize("mp,ps,pad_rows", [(4, 8, 0), (32, 16, 2)])
+    def test_single_masked_block_edge(self, mp, ps, pad_rows):
         """A sequence whose length leaves every page but the first fully
-        masked — the per-page online-softmax init/normalize edge."""
+        masked — the online-softmax init/normalize edge — beside the
+        bucket's padding rows: length 1, every table slot on page 0."""
         import jax.numpy as jnp
         from repro.kernels.decode_attention.ops import paged_decode_attention
         from repro.kernels.decode_attention.ref import \
             paged_decode_attention_ref
-        b, mp, ps = 2, 4, 8
+        b = 2 + pad_rows
         q, kp, vp, tables = self._data(b, mp, ps, 4, 4, 16, seed=3)
-        lengths = jnp.asarray([1, ps], jnp.int32)  # 1 token; exact boundary
+        tables = tables.at[2:].set(0)
+        lengths = jnp.asarray([1, ps] + [1] * pad_rows, jnp.int32)
         out = paged_decode_attention(q, kp, vp, tables, lengths,
                                      interpret=True, use_kernel=True)
         ref = paged_decode_attention_ref(q, kp, vp, tables, lengths)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5, rtol=3e-5)
 
-    def test_unused_pages_never_leak(self):
+    @pytest.mark.parametrize("mp,ps", [(3, 8), (8, 64)])
+    def test_unused_pages_never_leak(self, mp, ps):
         """Garbage in pages past ``length`` (including other sequences'
-        pages) must not change the output at all."""
+        pages) must not change the output at all; a whole block past the
+        length is never read, so even NaNs there cannot leak."""
         import jax.numpy as jnp
-        from repro.kernels.decode_attention.ops import paged_decode_attention
-        b, mp, ps = 2, 3, 8
+        from repro.kernels.decode_attention.ops import (
+            paged_decode_attention, pages_per_block)
+        b = 2
+        ppb = pages_per_block(mp, ps)
         q, kp, vp, tables = self._data(b, mp, ps, 4, 2, 16, seed=5)
-        lengths = jnp.asarray([5, 11], jnp.int32)
+        lengths = jnp.asarray([5, ps + 3], jnp.int32)
         out1 = paged_decode_attention(q, kp, vp, tables, lengths,
                                       interpret=True, use_kernel=True)
         # poison every page beyond each sequence's last valid one
         kp2 = kp.at[tables[0, 1:]].set(99.0).at[tables[1, 2:]].set(99.0)
         vp2 = vp.at[tables[0, 1:]].set(-99.0).at[tables[1, 2:]].set(-99.0)
-        # ... and the in-page tail of the last valid page
+        # ... the in-page tail of the last valid page
         kp2 = kp2.at[tables[0, 0], :, 5:].set(99.0)
         vp2 = vp2.at[tables[0, 0], :, 5:].set(-99.0)
+        # ... and every whole block past the length with NaN
+        live = [-(-int(n) // (ppb * ps)) * ppb for n in lengths]
+        dead = jnp.concatenate([tables[i, n:] for i, n in enumerate(live)])
+        kp2, vp2 = kp2.at[dead].set(jnp.nan), vp2.at[dead].set(jnp.nan)
         out2 = paged_decode_attention(q, kp2, vp2, tables, lengths,
                                       interpret=True, use_kernel=True)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
 
+    @pytest.mark.parametrize("mp,ps,ppb", [(128, 16, 16), (3, 16, 1),
+                                            (6, 16, 2), (8, 64, 4),
+                                            (4, 512, 1)])
+    def test_pages_per_block(self, mp, ps, ppb):
+        """The largest power of two dividing the table, at most 256
+        tokens per block (SmolLM's 2048-token tables: 8 blocks of 16)."""
+        from repro.kernels.decode_attention.ops import pages_per_block
+        assert pages_per_block(mp, ps) == ppb
+
     @settings(max_examples=10, deadline=None)
-    @given(st.integers(1, 4), st.sampled_from([(2, 8), (3, 16)]),
+    @given(st.integers(1, 4),
+           st.sampled_from([(2, 8), (3, 16), (32, 16)]),
            st.integers(0, 10_000))
     def test_prop_kernel_equals_ref(self, b, geom, seed):
         import jax

@@ -190,6 +190,27 @@ def test_prefix_hit_kv_bytes_match_the_shapes(tiny_model):
         m.counter_value("kv_host_bytes", dir="h2d") == after["kv_host_bytes"]
 
 
+def test_decode_counts_the_paged_kernels_blocks(tiny_model):
+    """One decode launch of three sequences in the 4-row bucket: 96-token
+    tables of 16-token pages are 6 pages, blocks of 2 pages (32 tokens),
+    3 blocks a row.  Contexts 0, 16 and 40 attend over 1, 17 and 41
+    tokens (1, 1 and 2 blocks); the padding row over 1 (1 block)."""
+    eng = _engine(tiny_model)
+    tel = Telemetry()
+    eng.attach_telemetry(tel)
+    run = eng._runners[eng.units[0].machine.mid]
+    assert (run.mp, run.ppb) == (6, 2)
+    seqs = [object() for _ in range(3)]
+    for i, (s, n) in enumerate(zip(seqs, (0, 16, 40))):
+        run.states[id(s)] = {"cur": 1, "len": n, "out": [],
+                             "tab": np.arange(1 + 6 * i, 7 + 6 * i)}
+    run._decode(seqs, tel)
+    m = tel.metrics
+    assert m.counter_value("paged_kv_blocks", kind="live") == 5
+    assert m.counter_value("paged_kv_blocks", kind="grid") == 4 * 3
+    assert [run.states[id(s)]["len"] for s in seqs] == [1, 17, 41]
+
+
 def test_spans_leave_greedy_tokens_unchanged(tiny_model):
     prompts = _prompts(6, seed=11)
     outs = []

@@ -86,6 +86,24 @@ def test_kernel_compiles_for_v5e(spec, name):
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
+# (batch, query heads, KV heads, head_dim): SmolLM-360M at each of the
+# engine's decode buckets, and Yi-9B's heads (GQA group 8, head_dim 128)
+PAGED_SHAPES = [(b, H, HKV, HD) for b in (1, 2, 4, 8, 16)] + [(8, 32, 4, 128)]
+
+
+@pytest.mark.parametrize("b,h,hkv,hd", PAGED_SHAPES,
+                         ids=[f"b{b}-h{h}-kv{k}-hd{d}"
+                              for b, h, k, d in PAGED_SHAPES])
+def test_paged_kernel_compiles_per_bucket(spec, b, h, hkv, hd):
+    """The paged kernel's blocks of 16 pages stay within the chip's
+    tiling and fast memory at every bucket and head width it serves."""
+    pages = spec((16 * MP + 1, hkv, PS, hd), jnp.bfloat16)
+    text = _compiled_text(paged_decode_attention_pallas,
+                          spec((b, h, hd), jnp.bfloat16), pages, pages,
+                          spec((b, MP), jnp.int32), spec((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.fixture(scope="module")
 def smollm(spec):
     """SmolLM-360M at its published widths, cut to two layers, as shapes."""
